@@ -883,6 +883,9 @@ def check_ratio_guards(guards, collected):
 
 
 def main(argv=None) -> int:
+    from repro.util import init_compile_cache
+
+    init_compile_cache()
     jax.config.update("jax_enable_x64", True)  # the paper's solvers are f64
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")

@@ -27,6 +27,7 @@ from repro.core.cahn_hilliard import (
     deep_quench_ic,
 )
 from repro.core.metrics import fit_power_law
+from repro.util import init_compile_cache
 
 jax.config.update("jax_enable_x64", True)
 
@@ -37,6 +38,7 @@ def chemical_potential(lap_plan, c, gamma):
 
 
 def main():
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=256)
     ap.add_argument("--t", type=float, default=8.0, help="final time")

@@ -44,9 +44,11 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402
 
 import repro  # noqa: E402
+from repro.util import init_compile_cache  # noqa: E402
 
 
 def main():
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=32, help="grid points per axis")
     ap.add_argument("--steps", type=int, default=100)

@@ -18,11 +18,13 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro
+from repro.util import init_compile_cache
 
 jax.config.update("jax_enable_x64", True)
 
 
 def main():
+    init_compile_cache()
     ap = argparse.ArgumentParser(
         description="cuSten quickstart on the repro four-function facade"
     )

@@ -10,9 +10,11 @@ supervisor enabled — the same code path as ``python -m repro.launch.train``.
 import argparse
 
 from repro.launch.train import train_loop
+from repro.util import init_compile_cache
 
 
 def main():
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--steps", type=int, default=200)

@@ -19,11 +19,13 @@ from repro.core.weno import (
     gaussian_blob,
     solid_body_rotation,
 )
+from repro.util import init_compile_cache
 
 jax.config.update("jax_enable_x64", True)
 
 
 def main():
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=128)
     ap.add_argument("--revolutions", type=float, default=1.0)

@@ -43,11 +43,7 @@ The package is organised as a production framework:
 
 __version__ = "2.0.0"  # tracks cuSten's published version
 
-from repro import _compat
-
-_compat.install()  # backport newer-jax API points onto the pinned jax
-
-from repro.api import (  # noqa: E402
+from repro.api import (
     OperatorDef,
     compute,
     create,
@@ -58,14 +54,14 @@ from repro.api import (  # noqa: E402
     register_operator,
     swap,
 )
-from repro.core.adi import (  # noqa: E402
+from repro.core.adi import (
     ADIOperator,
     ADIOperator3D,
     make_adi_operator,
     make_adi_operator_3d,
 )
-from repro.kernels.spectral import SpectralBackendError  # noqa: E402
-from repro.core.stencil import (  # noqa: E402
+from repro.kernels.spectral import SpectralBackendError
+from repro.core.stencil import (
     DoubleBuffer,
     PlanCore,
     Stencil2D,

@@ -28,8 +28,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.penta import rows_substitute_refs, rows_woodbury_correct
+from repro.kernels.penta import (
+    VMEM_LIMIT_BYTES,
+    _fac_table,
+    _smem_table_spec,
+    rows_woodbury_correct,
+    sweep_refs,
+    tpu_sweep_problem,
+    woodbury_rows,
+)
+from repro.util import block_spec, wrap_block
 
 _H = 2  # biharmonic halo
 
@@ -118,11 +128,10 @@ def ch_rhs_pallas(
     if ny % ty or nx % tx:
         raise ValueError(f"tile ({ty},{tx}) must divide field ({ny},{nx})")
     gy, gx = ny // ty, nx // tx
-    wrap = lambda k, n: jnp.remainder(k, n).astype(jnp.int32)  # noqa: E731
 
     def spec(dj, di):
-        return pl.BlockSpec(
-            (ty, tx), lambda j, i: (wrap(j + dj, gy), wrap(i + di, gx))
+        return block_spec(
+            (ty, tx), lambda j, i: (wrap_block(j + dj, gy), wrap_block(i + di, gx))
         )
 
     neigh = [(dj, di) for dj in (-1, 0, 1) for di in (-1, 0, 1)]
@@ -135,7 +144,7 @@ def ch_rhs_pallas(
         ),
         grid=(gy, gx),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((ty, tx), lambda j, i: (j, i)),
+        out_specs=block_spec((ty, tx), lambda j, i: (j, i)),
         out_shape=jax.ShapeDtypeStruct((ny, nx), c_n.dtype),
         interpret=interpret,
     )(*operands)
@@ -148,18 +157,17 @@ def ch_rhs_pallas(
 
 
 def _ch_xsweep_kernel(
-    *refs, dt, D, gamma, inv_h2, inv_h4, ty, nx,
+    *refs, dt, D, gamma, inv_h2, inv_h4, ty, hb, nx,
 ):
-    # refs: 3 row-band tiles of c_n (dj = -1, 0, 1), 3 of c_nm1,
-    #       sub, low, inv_mu, al, be (each (nx,)), w (nx, 4), out (ty, nx)
+    # refs: c_n's hb-row halo block above, (ty, nx) row band, halo block
+    #       below; the same three of c_nm1; the (5, nx) SMEM factor table;
+    #       W^T (4, nx); out (ty, nx)
     cn_tiles = [r[...] for r in refs[:3]]
     cm_tiles = [r[...] for r in refs[3:6]]
-    sub_ref, low_ref, imu_ref, al_ref, be_ref = refs[6:11]
-    w_ref = refs[11]
-    o_ref = refs[-1]
+    f_ref, wt_ref, o_ref = refs[6:]
 
-    def assemble(tm1, t0, tp1):
-        band = jnp.concatenate([tm1[ty - _H :, :], t0, tp1[:_H, :]], axis=0)
+    def assemble(above, mid, below):
+        band = jnp.concatenate([above[hb - _H :, :], mid, below[:_H, :]], axis=0)
         return jnp.concatenate(
             [band[:, nx - _H :], band, band[:, :_H]], axis=1
         )  # periodic x wrap inside the full-width band
@@ -182,12 +190,46 @@ def _ch_xsweep_kernel(
     # Row-layout substitution in place (the RHS never leaves VMEM), then
     # the Woodbury closure — both shared with kernels/penta.py so the
     # fused kernel stays in lockstep with the standalone solve.
-    rows_substitute_refs(
-        sub_ref, low_ref, imu_ref, al_ref, be_ref, o_ref, M=nx, Tb=ty
-    )
-    o_ref[...] = rows_woodbury_correct(o_ref[...], w_ref[...]).astype(
+    sweep_refs(f_ref, o_ref, axis=1)
+    o_ref[...] = rows_woodbury_correct(o_ref[...], wt_ref[...]).astype(
         o_ref.dtype
     )
+
+
+# VMEM the fused kernel's row band may take, under VMEM_LIMIT_BYTES: the
+# compiler also keeps Mosaic's own relayout copies there.
+XSWEEP_VMEM_BUDGET = 24 * 2**20
+
+
+def xsweep_vmem_bytes(ty: int, nx: int, itemsize: int = 4) -> int:
+    """Estimated VMEM of one fused-kernel grid step: the double-buffered
+    row bands and halo blocks of both fields and the output band, plus
+    about a dozen (ty+4, nx+4) band temporaries (cn, cm, cbar, nl and the
+    stencil terms), each padded to the (8, 128) tile."""
+    pad = lambda n, m: -(-n // m) * m  # noqa: E731
+    blocks = 2 * (2 * (ty + 16) + ty) * nx
+    temps = 12 * pad(ty + 2 * _H, 8) * pad(nx + 2 * _H, 128)
+    return (blocks + temps) * itemsize
+
+
+def xsweep_tile(ny: int, nx: int, itemsize: int = 4) -> int:
+    """Largest 8-aligned row band dividing ``ny`` whose step fits
+    :data:`XSWEEP_VMEM_BUDGET` (at least 8 where 8 divides ``ny``)."""
+    fits = [
+        t for t in (256, 128, 64, 32, 16, 8)
+        if ny % t == 0 and xsweep_vmem_bytes(t, nx, itemsize) <= XSWEEP_VMEM_BUDGET
+    ]
+    return fits[0] if fits else (8 if ny % 8 == 0 else ny)
+
+
+def xsweep_tpu_problem(ny: int, nx: int, ty: int, dtype) -> str | None:
+    """Why :func:`ch_rhs_xsweep_pallas` cannot be compiled for a TPU with
+    ``ty``-row bands, or ``None`` when it can."""
+    if ty < _H:
+        return f"row tile {ty} is below the halo {_H}"
+    if xsweep_vmem_bytes(ty, nx, jnp.dtype(dtype).itemsize) > XSWEEP_VMEM_BUDGET:
+        return f"a ({ty}, {nx}) row band exceeds the VMEM budget"
+    return tpu_sweep_problem(nx, ny, ty, dtype, lanes=True)
 
 
 @functools.partial(
@@ -213,7 +255,9 @@ def ch_rhs_xsweep_pallas(
 
     ``fac_x`` is a :class:`repro.kernels.penta.CyclicPentaFactors` of
     length ``nx``.  Tiles are full-width row bands (the lane recurrence
-    needs the whole x extent in VMEM); the grid walks the y axis.
+    needs the whole x extent in VMEM); the grid walks the y axis.  The
+    y halos come from the 8-row blocks above and below the band (the
+    whole band where ``ty`` is not a multiple of 8).
     """
     ny, nx = c_n.shape
     if ny % ty:
@@ -221,31 +265,31 @@ def ch_rhs_xsweep_pallas(
     if ty < _H:
         raise ValueError(f"row tile {ty} must be >= halo {_H}")
     gy = ny // ty
-    wrap = lambda k: jnp.remainder(k, gy).astype(jnp.int32)  # noqa: E731
+    hb = 8 if ty % 8 == 0 else ty  # halo block rows
+    r = ty // hb  # halo blocks per band
+    nh = ny // hb
 
-    def spec(dj):
-        return pl.BlockSpec((ty, nx), lambda j, dj=dj: (wrap(j + dj), 0))
-
-    band = fac_x.band
-    vec_spec = pl.BlockSpec((nx,), lambda j: (0,))
-    in_specs = (
-        [spec(dj) for dj in (-1, 0, 1)] * 2
-        + [vec_spec] * 5
-        + [pl.BlockSpec((nx, 4), lambda j: (0, 0))]
+    band = block_spec((ty, nx), lambda j: (j, 0))
+    above = block_spec(
+        (hb, nx), lambda j: (wrap_block(j * r - 1, nh), 0)
     )
-    operands = (
-        [c_n] * 3
-        + [c_nm1] * 3
-        + [band.sub, band.low, band.inv_mu, band.al, band.be, fac_x.w]
+    below = block_spec(
+        (hb, nx), lambda j: (wrap_block(j * r + r, nh), 0)
     )
+    in_specs = [above, band, below] * 2 + [
+        _smem_table_spec(nx),
+        block_spec((4, nx), lambda j: (0, 0)),
+    ]
+    operands = [c_n] * 3 + [c_nm1] * 3 + [_fac_table(fac_x.band), woodbury_rows(fac_x.w)]
     return pl.pallas_call(
         functools.partial(
             _ch_xsweep_kernel, dt=dt, D=D, gamma=gamma,
-            inv_h2=inv_h2, inv_h4=inv_h4, ty=ty, nx=nx,
+            inv_h2=inv_h2, inv_h4=inv_h4, ty=ty, hb=hb, nx=nx,
         ),
         grid=(gy,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((ty, nx), lambda j: (j, 0)),
+        out_specs=band,
         out_shape=jax.ShapeDtypeStruct((ny, nx), c_n.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)

@@ -2,12 +2,14 @@
 
 Every op takes ``backend ∈ {'auto', 'pallas', 'jnp'}``:
 
-- ``pallas``  — the TPU kernel (``interpret=True`` automatically when no TPU
-  is attached, so the same call validates on CPU);
+- ``pallas``  — the TPU kernel (interpret mode when no TPU is attached, so
+  the same call runs on CPU; interpret mode cannot show what the TPU
+  compiler refuses, which ``tests/test_tpu_compile.py`` checks);
 - ``jnp``     — the pure-jnp oracle from :mod:`repro.kernels.ref`, which XLA
   fuses well and is the production CPU path;
 - ``auto``    — pallas when the kernel's structural constraints (tile
-  divisibility, halo <= tile) hold on a TPU backend, otherwise jnp.
+  divisibility, halo <= tile, a dtype Mosaic compiles) hold on a TPU
+  backend, otherwise jnp.
 
 This mirrors cuSten's "the library picks the implementation details" design:
 callers state the math, dispatch is the library's job.
@@ -43,8 +45,25 @@ def _pallas_dispatch(kernel: str) -> None:
     _chaos.fire("pallas.dispatch", kernel=kernel)
 
 
+def _tpu_pallas(dtype) -> bool:
+    """On a TPU, ``auto`` may pick a Pallas kernel for this dtype: Mosaic
+    compiles no 64-bit floats."""
+    return on_tpu() and jnp.dtype(dtype).itemsize <= 4
+
+
 def _should_interpret(interpret: bool | None) -> bool:
     return not on_tpu() if interpret is None else interpret
+
+
+def checked_backend(kernel: str, problem: str | None, backend: str, interpret) -> str:
+    """Resolve ``auto`` for a kernel whose TPU compile has a known
+    ``problem`` at this shape (``None`` when it compiles), and refuse
+    ``pallas`` for it on the chip."""
+    if backend == "auto":
+        return "pallas" if on_tpu() and problem is None else "jnp"
+    if backend == "pallas" and problem is not None and not _should_interpret(interpret):
+        raise ValueError(f"pallas {kernel} cannot run on this TPU: {problem}")
+    return backend
 
 
 def _pallas_ok(ny, nx, ty, tx, hx, hy) -> bool:
@@ -62,6 +81,12 @@ def _aligned(t: int, align: int = 8) -> bool:
     """Sublane-aligned tile (the implicit-tile quality bar — an awkward
     extent like 127 should pad to 128, not run as one misaligned tile)."""
     return t % align == 0
+
+
+def _lane_aligned(t: int, n: int) -> bool:
+    """A last-axis tile Mosaic accepts: whole 128-lane vregs, or the whole
+    extent."""
+    return t % 128 == 0 or t == n
 
 
 def _halo_pad_2d(data, *, top, bottom, left, right, bc):
@@ -182,15 +207,16 @@ def stencil_apply(
     ty, tx = tile if tile is not None else (pick_tile(ny), pick_tile(nx))
 
     # explicit tiles keep the historical contract (divide + cover halo);
-    # implicit tiles must additionally be sublane-aligned, else the
-    # alignment-padded dispatch below takes over
+    # implicit tiles must additionally be sublane-aligned (and lane-aligned
+    # along x), else the alignment-padded dispatch below takes over
     clean = _pallas_ok(ny, nx, ty, tx, hx, hy) and (
-        tile is not None or (_aligned(ty) and _aligned(tx))
+        tile is not None
+        or (_aligned(ty) and _aligned(tx) and _lane_aligned(tx, nx))
     )
     if backend == "auto":
         backend = (
             "pallas"
-            if on_tpu()
+            if _tpu_pallas(data.dtype)
             and (clean or (tile is None and hy <= ny and hx <= nx))
             else "jnp"
         )
@@ -208,7 +234,7 @@ def stencil_apply(
 
             sy, sx = ny + top + bottom, nx + left + right
             pty, py = pick_tile_padded(sy)
-            ptx, px = pick_tile_padded(sx)
+            ptx, px = pick_tile_padded(sx, align=128)
             if pty < hy:
                 pty = next_multiple(hy, 8)
                 py = next_multiple(sy, pty)
@@ -314,12 +340,13 @@ def stencil_apply_batch1d(
     tb, tm = tile if tile is not None else (pick_tile_any(B), pick_tile_any(M))
 
     clean = _pallas_ok_1d(B, M, tb, tm, hm) and (
-        tile is not None or (_aligned(tb) and _aligned(tm))
+        tile is not None
+        or (_aligned(tb) and _aligned(tm) and _lane_aligned(tm, M))
     )
     if backend == "auto":
         backend = (
             "pallas"
-            if on_tpu() and (clean or (tile is None and hm <= M))
+            if _tpu_pallas(data.dtype) and (clean or (tile is None and hm <= M))
             else "jnp"
         )
     if backend == "pallas":
@@ -334,7 +361,7 @@ def stencil_apply_batch1d(
 
             sm = M + left + right
             ptb, pb = pick_tile_padded(B)
-            ptm, pm = pick_tile_padded(sm, target=256)
+            ptm, pm = pick_tile_padded(sm, target=256, align=128)
             if ptm < hm:
                 ptm = next_multiple(hm, 8)
                 pm = next_multiple(sm, ptm)
@@ -482,7 +509,7 @@ def stencil_apply_3d(
     if backend == "auto":
         backend = (
             "pallas"
-            if on_tpu()
+            if _tpu_pallas(data.dtype)
             and (clean or (tile is None and hz <= nz and hy <= ny and hx <= nx))
             else "jnp"
         )
@@ -583,7 +610,11 @@ def weno_advect(
     ny, nx = q.shape
     ty, tx = tile if tile is not None else (pick_tile(ny), pick_tile(nx))
     if backend == "auto":
-        backend = "pallas" if on_tpu() and _pallas_ok(ny, nx, ty, tx, 3, 3) else "jnp"
+        backend = (
+            "pallas"
+            if _tpu_pallas(q.dtype) and _pallas_ok(ny, nx, ty, tx, 3, 3)
+            else "jnp"
+        )
     if backend == "pallas":
         _pallas_dispatch("weno5_advect")
         return weno5_advect_pallas(
@@ -614,7 +645,11 @@ def ch_rhs(
     ny, nx = c_n.shape
     ty, tx = tile if tile is not None else (pick_tile(ny), pick_tile(nx))
     if backend == "auto":
-        backend = "pallas" if on_tpu() and _pallas_ok(ny, nx, ty, tx, 2, 2) else "jnp"
+        backend = (
+            "pallas"
+            if _tpu_pallas(c_n.dtype) and _pallas_ok(ny, nx, ty, tx, 2, 2)
+            else "jnp"
+        )
     if backend == "pallas":
         _pallas_dispatch("ch_rhs")
         return ch_rhs_pallas(
@@ -642,15 +677,19 @@ def ch_rhs_xsweep(
     cases the RHS feeds the sweep in its native row layout with no
     intermediate transpose.
     """
-    from repro.kernels.fused_ch import ch_rhs_xsweep_pallas
+    from repro.kernels.fused_ch import (
+        ch_rhs_xsweep_pallas,
+        xsweep_tile,
+        xsweep_tpu_problem,
+    )
     from repro.kernels.penta import cyclic_penta_solve_factored_rows
 
     ny, nx = c_n.shape
-    ty = ty if ty is not None else pick_tile(ny)
-    if backend == "auto":
-        backend = (
-            "pallas" if on_tpu() and ny % ty == 0 and ty >= 2 else "jnp"
-        )
+    ty = ty if ty is not None else xsweep_tile(ny, nx, c_n.dtype.itemsize)
+    backend = checked_backend(
+        "ch_rhs_xsweep", xsweep_tpu_problem(ny, nx, ty, c_n.dtype), backend,
+        interpret,
+    )
     if backend == "pallas":
         _pallas_dispatch("ch_rhs_xsweep")
         return ch_rhs_xsweep_pallas(

@@ -53,9 +53,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.util import pick_tile
+from repro.util import block_spec, pick_tile
 
 
 class PentaFactors(NamedTuple):
@@ -262,183 +264,202 @@ def mid_woodbury_correct(y: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Substitution — Pallas kernel (TPU target; interpret=True on CPU)
+# Substitution — Pallas kernel (TPU target; interpret mode off the chip)
 # ---------------------------------------------------------------------------
 
+# The five factor vectors travel as one (5, M) table in SMEM and are read
+# as scalars: Mosaic refuses single-element dynamic loads from a 1D VMEM
+# vector.  A float32 table fits SMEM up to this recurrence length (AOT
+# compiles for TPU v5e pass at 8192 and fail at 32768).
+SMEM_MAX_M = 8192
+# Scoped VMEM the sweep kernels may use: half of a v5e core's 128 MiB (the
+# compiler's default scoped limit, 16 MiB, is too small for a 4096-row
+# column block at M = 8192).
+VMEM_LIMIT_BYTES = 64 * 2**20
 
-def _substitute_kernel(sub_ref, low_ref, imu_ref, al_ref, be_ref, r_ref, o_ref, *, M, Tn):
-    zero = jnp.zeros((1, Tn), r_ref.dtype)
 
-    def fwd(i, carry):
+def _fac_table(fac: PentaFactors) -> jnp.ndarray:
+    return jnp.stack([fac.sub, fac.low, fac.inv_mu, fac.al, fac.be])
+
+
+def _chunk(M: int, lanes: bool) -> int:
+    """Recurrence steps per block: the largest divisor of ``M`` up to one
+    vreg extent (128 lanes along the last axis, 8 sublanes otherwise)."""
+    align = 128 if lanes else 8
+    return max(c for c in range(1, min(M, align) + 1) if M % c == 0)
+
+
+def sweep_refs(f_ref, o_ref, *, axis: int) -> None:
+    """In-place banded substitution along ``axis`` of the Pallas ref
+    ``o_ref``, which holds the right-hand side on entry and the solution on
+    exit; ``f_ref`` is the (5, M) SMEM factor table.
+
+    The one recurrence of every layout: column and plane sweeps walk the
+    sublanes (axis 0 or 1 of a (1, M, tn) block), the row sweep and the
+    fused CH kernel walk the lanes (axis 1 of a (tb, M) block).  Mosaic
+    refuses dynamic single-lane accesses and unaligned single-row ones,
+    so the loop loads and stores whole aligned blocks of
+    :func:`_chunk` steps and runs the steps inside a block unrolled, on
+    static slices.
+    """
+    nd = len(o_ref.shape)
+    M = o_ref.shape[axis]
+    chunk = _chunk(M, lanes=axis == nd - 1)
+    n = M // chunk
+
+    def at(off):
+        return tuple(
+            pl.ds(off, chunk) if d == axis else slice(None) for d in range(nd)
+        )
+
+    def step(blk, j):
+        return jax.lax.slice_in_dim(blk, j, j + 1, axis=axis)
+
+    first = o_ref[at(0)]
+    pos = jax.lax.broadcasted_iota(jnp.int32, first.shape, axis)
+    # a zero laid out like a loaded step: Mosaic cannot carry a constant
+    # (replicated-layout) zero through the loop
+    zero = step(first, 0) * 0
+
+    # every integer is int32: under x64 a Python int lowers to an int64
+    # that Mosaic cannot mix with the int32 loop index
+    c32 = np.int32(chunk)
+
+    def fwd(k, carry):
         z1, z2 = carry
-        r = pl.load(r_ref, (pl.ds(i, 1), slice(None)))
-        e = pl.load(sub_ref, (pl.ds(i, 1),))
-        lo = pl.load(low_ref, (pl.ds(i, 1),))
-        im = pl.load(imu_ref, (pl.ds(i, 1),))
-        z = (r - e * z2 - lo * z1) * im
-        pl.store(o_ref, (pl.ds(i, 1), slice(None)), z)
-        return (z, z1)
-
-    jax.lax.fori_loop(0, M, fwd, (zero, zero))
+        off = pl.multiple_of(k * c32, chunk)
+        blk = o_ref[at(off)]
+        out = blk
+        for j in range(chunk):
+            i = off + np.int32(j)
+            z = (step(blk, j) - f_ref[0, i] * z2 - f_ref[1, i] * z1) * f_ref[2, i]
+            out = jnp.where(pos == np.int32(j), z, out)
+            z1, z2 = z, z1
+        o_ref[at(off)] = out
+        return z1, z2
 
     def bwd(t, carry):
         x1, x2 = carry
-        i = M - 1 - t
-        z = pl.load(o_ref, (pl.ds(i, 1), slice(None)))
-        al = pl.load(al_ref, (pl.ds(i, 1),))
-        be = pl.load(be_ref, (pl.ds(i, 1),))
-        x = z - al * x1 - be * x2
-        pl.store(o_ref, (pl.ds(i, 1), slice(None)), x)
-        return (x, x1)
+        off = pl.multiple_of((np.int32(n - 1) - t) * c32, chunk)
+        blk = o_ref[at(off)]
+        out = blk
+        for j in reversed(range(chunk)):
+            i = off + np.int32(j)
+            x = step(blk, j) - f_ref[3, i] * x1 - f_ref[4, i] * x2
+            out = jnp.where(pos == np.int32(j), x, out)
+            x1, x2 = x, x1
+        o_ref[at(off)] = out
+        return x1, x2
 
-    jax.lax.fori_loop(0, M, bwd, (zero, zero))
-
-
-@functools.partial(jax.jit, static_argnames=("tn", "interpret"))
-def _substitute_pallas(
-    fac: PentaFactors, rhs: jnp.ndarray, *, tn: int, interpret: bool
-) -> jnp.ndarray:
-    M, N = rhs.shape
-    if N % tn:
-        raise ValueError(f"batch tile {tn} must divide N={N}")
-    vec_spec = pl.BlockSpec((M,), lambda i: (0,))
-    return pl.pallas_call(
-        functools.partial(_substitute_kernel, M=M, Tn=tn),
-        grid=(N // tn,),
-        in_specs=[vec_spec] * 5 + [pl.BlockSpec((M, tn), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((M, tn), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((M, N), rhs.dtype),
-        interpret=interpret,
-    )(fac.sub, fac.low, fac.inv_mu, fac.al, fac.be, rhs)
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(n), fwd, (zero, zero))
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(n), bwd, (zero, zero))
 
 
-def rows_substitute_refs(
-    sub_ref, low_ref, imu_ref, al_ref, be_ref, o_ref, *, M, Tb
-):
-    """In-place row-layout substitution on Pallas refs: ``o_ref`` holds the
-    (Tb, M) right-hand side on entry and the solution on exit.  The
-    recurrence strides the lanes (axis 1), carrying two previous *columns*
-    in vector registers.  Shared by the standalone row-layout kernel and
-    the fused RHS+x-sweep kernel so the two stay in lockstep."""
-    zero = jnp.zeros((Tb, 1), o_ref.dtype)
-
-    def fwd(i, carry):
-        z1, z2 = carry
-        r = pl.load(o_ref, (slice(None), pl.ds(i, 1)))
-        e = pl.load(sub_ref, (pl.ds(i, 1),))
-        lo = pl.load(low_ref, (pl.ds(i, 1),))
-        im = pl.load(imu_ref, (pl.ds(i, 1),))
-        z = (r - e * z2 - lo * z1) * im
-        pl.store(o_ref, (slice(None), pl.ds(i, 1)), z)
-        return (z, z1)
-
-    jax.lax.fori_loop(0, M, fwd, (zero, zero))
-
-    def bwd(t, carry):
-        x1, x2 = carry
-        i = M - 1 - t
-        z = pl.load(o_ref, (slice(None), pl.ds(i, 1)))
-        al = pl.load(al_ref, (pl.ds(i, 1),))
-        be = pl.load(be_ref, (pl.ds(i, 1),))
-        x = z - al * x1 - be * x2
-        pl.store(o_ref, (slice(None), pl.ds(i, 1)), x)
-        return (x, x1)
-
-    jax.lax.fori_loop(0, M, bwd, (zero, zero))
+def tpu_sweep_problem(M, batch, tile, dtype, *, lanes: bool) -> str | None:
+    """Why the Pallas sweep cannot be compiled for a TPU at this shape, or
+    ``None`` when it can.  ``lanes`` says the recurrence runs along the
+    lanes (row layout); ``batch``/``tile`` are the tiled batch extent and
+    its block (rows of the row layout, lanes of the column/plane ones)."""
+    if jnp.dtype(dtype).itemsize != 4:
+        return f"Mosaic has no {jnp.dtype(dtype).name} sweep (float32 only)"
+    if batch % tile:
+        return f"batch tile {tile} does not divide {batch}"
+    align = 8 if lanes else 128
+    if tile % align and tile != batch:
+        return f"batch tile {tile} is not a multiple of {align}"
+    if _chunk(M, lanes) not in (128 if lanes else 8, M):
+        return f"recurrence length {M} is not a multiple of {128 if lanes else 8}"
+    if M > SMEM_MAX_M:
+        return f"recurrence length {M} > {SMEM_MAX_M}: factors exceed SMEM"
+    if 4 * 4 * M * tile > VMEM_LIMIT_BYTES:  # in + out, double-buffered
+        return f"a ({M}, {tile}) block exceeds the VMEM limit"
+    return None
 
 
-def rows_woodbury_correct(y: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """Row-layout Woodbury closure ``x = y - W (V^T y)`` on a (B, M) band
-    solution, as four broadcast FMAs (``w`` is the Create-time (M, 4)
-    ``Z S^{-1}``).  Shared by the jnp solve and the fused Pallas kernel."""
-    M = y.shape[1]
-    return y - (
-        y[:, M - 2][:, None] * w[None, :, 0]
-        + y[:, M - 1][:, None] * w[None, :, 1]
-        + y[:, 0][:, None] * w[None, :, 2]
-        + y[:, 1][:, None] * w[None, :, 3]
-    )
+def _smem_table_spec(M: int):
+    """The whole (5, M) factor table in SMEM at every grid step."""
+    return block_spec((5, M), lambda *_: (0, 0), memory_space=pltpu.SMEM)
 
 
-def _substitute_rows_kernel(
-    sub_ref, low_ref, imu_ref, al_ref, be_ref, r_ref, o_ref, *, M, Tb
-):
-    """Row-layout kernel: copy the RHS tile into the output ref, then run
-    the shared in-place lane recurrence."""
+def _solve_kernel(f_ref, r_ref, o_ref, *, axis):
     o_ref[...] = r_ref[...]
-    rows_substitute_refs(
-        sub_ref, low_ref, imu_ref, al_ref, be_ref, o_ref, M=M, Tb=Tb
-    )
+    sweep_refs(f_ref, o_ref, axis=axis)
 
 
-@functools.partial(jax.jit, static_argnames=("tb", "interpret"))
-def _substitute_rows_pallas(
-    fac: PentaFactors, rhs: jnp.ndarray, *, tb: int, interpret: bool
-) -> jnp.ndarray:
-    B, M = rhs.shape
-    if B % tb:
-        raise ValueError(f"batch tile {tb} must divide B={B}")
-    vec_spec = pl.BlockSpec((M,), lambda i: (0,))
+def _pallas_sweep(fac, rhs, *, block, index_map, grid, interpret):
+    """One sweep ``pallas_call``: the recurrence runs along axis 1 of every
+    ``block`` (the middle axis of a plane block, the lanes of a row block)."""
     return pl.pallas_call(
-        functools.partial(_substitute_rows_kernel, M=M, Tb=tb),
-        grid=(B // tb,),
-        in_specs=[vec_spec] * 5 + [pl.BlockSpec((tb, M), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tb, M), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, M), rhs.dtype),
+        functools.partial(_solve_kernel, axis=1),
+        grid=grid,
+        in_specs=[
+            _smem_table_spec(rhs.shape[1]),
+            block_spec(block, index_map),
+        ],
+        out_specs=block_spec(block, index_map),
+        out_shape=jax.ShapeDtypeStruct(rhs.shape, rhs.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(fac.sub, fac.low, fac.inv_mu, fac.al, fac.be, rhs)
-
-
-def _substitute_mid_kernel(
-    sub_ref, low_ref, imu_ref, al_ref, be_ref, r_ref, o_ref, *, M, Tn
-):
-    """Plane-layout kernel on a (1, M, Tn) block: one z-plane × lane tile
-    per grid step, recurrence striding the middle axis with two previous
-    planes carried in vector registers (the row-layout lane recurrence of
-    :func:`rows_substitute_refs`, one axis deeper)."""
-    zero = jnp.zeros((1, 1, Tn), o_ref.dtype)
-
-    def fwd(i, carry):
-        z1, z2 = carry
-        r = pl.load(r_ref, (slice(None), pl.ds(i, 1), slice(None)))
-        e = pl.load(sub_ref, (pl.ds(i, 1),))
-        lo = pl.load(low_ref, (pl.ds(i, 1),))
-        im = pl.load(imu_ref, (pl.ds(i, 1),))
-        z = (r - e * z2 - lo * z1) * im
-        pl.store(o_ref, (slice(None), pl.ds(i, 1), slice(None)), z)
-        return (z, z1)
-
-    jax.lax.fori_loop(0, M, fwd, (zero, zero))
-
-    def bwd(t, carry):
-        x1, x2 = carry
-        i = M - 1 - t
-        z = pl.load(o_ref, (slice(None), pl.ds(i, 1), slice(None)))
-        al = pl.load(al_ref, (pl.ds(i, 1),))
-        be = pl.load(be_ref, (pl.ds(i, 1),))
-        x = z - al * x1 - be * x2
-        pl.store(o_ref, (slice(None), pl.ds(i, 1), slice(None)), x)
-        return (x, x1)
-
-    jax.lax.fori_loop(0, M, bwd, (zero, zero))
+    )(_fac_table(fac), rhs)
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def _substitute_mid_pallas(
     fac: PentaFactors, rhs: jnp.ndarray, *, tn: int, interpret: bool
 ) -> jnp.ndarray:
+    """Plane layout on (P, M, N): one z-plane × lane tile per grid step."""
     P, M, N = rhs.shape
     if N % tn:
         raise ValueError(f"lane tile {tn} must divide N={N}")
-    vec_spec = pl.BlockSpec((M,), lambda p, i: (0,))
-    return pl.pallas_call(
-        functools.partial(_substitute_mid_kernel, M=M, Tn=tn),
-        grid=(P, N // tn),
-        in_specs=[vec_spec] * 5 + [pl.BlockSpec((1, M, tn), lambda p, i: (p, 0, i))],
-        out_specs=pl.BlockSpec((1, M, tn), lambda p, i: (p, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((P, M, N), rhs.dtype),
-        interpret=interpret,
-    )(fac.sub, fac.low, fac.inv_mu, fac.al, fac.be, rhs)
+    return _pallas_sweep(
+        fac, rhs, block=(1, M, tn), index_map=lambda p, i: (p, 0, i),
+        grid=(P, N // tn), interpret=interpret,
+    )
+
+
+def _substitute_pallas(
+    fac: PentaFactors, rhs: jnp.ndarray, *, tn: int, interpret: bool
+) -> jnp.ndarray:
+    """Column layout on (M, N): the plane layout with a single plane."""
+    M, N = rhs.shape
+    if N % tn:
+        raise ValueError(f"batch tile {tn} must divide N={N}")
+    return _substitute_mid_pallas(fac, rhs[None], tn=tn, interpret=interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("tb", "interpret"))
+def _substitute_rows_pallas(
+    fac: PentaFactors, rhs: jnp.ndarray, *, tb: int, interpret: bool
+) -> jnp.ndarray:
+    """Row layout on (B, M): the recurrence walks the lanes of (tb, M)."""
+    B, M = rhs.shape
+    if B % tb:
+        raise ValueError(f"batch tile {tb} must divide B={B}")
+    return _pallas_sweep(
+        fac, rhs, block=(tb, M), index_map=lambda i: (i, 0),
+        grid=(B // tb,), interpret=interpret,
+    )
+
+
+def woodbury_rows(w: jnp.ndarray) -> jnp.ndarray:
+    """The Create-time (M, 4) ``Z S^{-1}`` as a (4, M) stack of its
+    columns, for :func:`rows_woodbury_correct` (column slices, so the
+    transpose-free paths hold no transpose)."""
+    return jnp.stack([w[:, k] for k in range(4)])
+
+
+def rows_woodbury_correct(y: jnp.ndarray, wt: jnp.ndarray) -> jnp.ndarray:
+    """Row-layout Woodbury closure ``x = y - W (V^T y)`` on a (B, M) band
+    solution, as four broadcast FMAs (``wt`` is :func:`woodbury_rows` of
+    the Create-time ``Z S^{-1}``, so every slice is static).  Shared by the
+    jnp solve and the fused Pallas kernel."""
+    M = y.shape[1]
+    return y - (
+        y[:, M - 2 : M - 1] * wt[0:1]
+        + y[:, M - 1 : M] * wt[1:2]
+        + y[:, 0:1] * wt[2:3]
+        + y[:, 1:2] * wt[3:4]
+    )
 
 
 _substitute_jnp_jit = jax.jit(_substitute_jnp, static_argnames=("unroll",))
@@ -467,12 +488,13 @@ def penta_solve_factored(
         rhs = rhs[:, None]
     M, N = rhs.shape
     tn = tn if tn is not None else pick_tile(N)
-    if backend == "auto":
-        backend = "pallas" if ops.on_tpu() and N % tn == 0 else "jnp"
+    backend = ops.checked_backend(
+        "penta sweep", tpu_sweep_problem(M, N, tn, rhs.dtype, lanes=False),
+        backend, interpret,
+    )
     if backend == "pallas":
         out = _substitute_pallas(
-            fac, rhs, tn=tn,
-            interpret=(not ops.on_tpu()) if interpret is None else interpret,
+            fac, rhs, tn=tn, interpret=ops._should_interpret(interpret)
         )
     elif backend == "jnp":
         out = _substitute_jnp_jit(fac, rhs, unroll=unroll)
@@ -502,12 +524,13 @@ def penta_solve_factored_rows(
         rhs = rhs[None, :]
     B, M = rhs.shape
     tb = tb if tb is not None else pick_tile(B)
-    if backend == "auto":
-        backend = "pallas" if ops.on_tpu() and B % tb == 0 else "jnp"
+    backend = ops.checked_backend(
+        "penta sweep", tpu_sweep_problem(M, B, tb, rhs.dtype, lanes=True),
+        backend, interpret,
+    )
     if backend == "pallas":
         out = _substitute_rows_pallas(
-            fac, rhs, tb=tb,
-            interpret=(not ops.on_tpu()) if interpret is None else interpret,
+            fac, rhs, tb=tb, interpret=ops._should_interpret(interpret)
         )
     elif backend == "jnp":
         out = _substitute_rows_jnp_jit(fac, rhs, unroll=unroll)
@@ -535,12 +558,13 @@ def penta_solve_factored_mid(
 
     P, M, N = rhs.shape
     tn = tn if tn is not None else pick_tile(N)
-    if backend == "auto":
-        backend = "pallas" if ops.on_tpu() and N % tn == 0 else "jnp"
+    backend = ops.checked_backend(
+        "penta sweep", tpu_sweep_problem(M, N, tn, rhs.dtype, lanes=False),
+        backend, interpret,
+    )
     if backend == "pallas":
         return _substitute_mid_pallas(
-            fac, rhs, tn=tn,
-            interpret=(not ops.on_tpu()) if interpret is None else interpret,
+            fac, rhs, tn=tn, interpret=ops._should_interpret(interpret)
         )
     if backend == "jnp":
         return _substitute_mid_jnp_jit(fac, rhs, unroll=unroll)
@@ -578,7 +602,10 @@ def cyclic_penta_factor(l2, l1, d, u1, u2) -> CyclicPentaFactors:
     vt_rows = jnp.stack([z[M - 2], z[M - 1], z[0], z[1]])  # V^T Z  (4, 4)
     s = jnp.eye(4, dtype=dt) + vt_rows
     s_inv = jnp.linalg.inv(s)
-    return CyclicPentaFactors(band=band, z=z, s_inv=s_inv, w=z @ s_inv)
+    # full f32 products: a TPU matmul at default precision rounds its
+    # operands to bfloat16, which left a 6e-3 residual in the 2D ADI solve
+    w = jnp.matmul(z, s_inv, precision=jax.lax.Precision.HIGHEST)
+    return CyclicPentaFactors(band=band, z=z, s_inv=s_inv, w=w)
 
 
 def cyclic_penta_solve_factored(
@@ -628,7 +655,7 @@ def cyclic_penta_solve_factored_rows(
         fac.band, rhs, backend=backend, tb=tb, interpret=interpret,
         unroll=unroll,
     )
-    x = rows_woodbury_correct(y, fac.w)
+    x = rows_woodbury_correct(y, woodbury_rows(fac.w))
     return x[0] if squeeze else x
 
 

@@ -39,19 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ref import weighted_point_fn
-
-
-def _wrap(i, n):
-    return jnp.remainder(i, n).astype(jnp.int32)
-
-
-def _clamp(i, n):
-    return jnp.clip(i, 0, n - 1).astype(jnp.int32)
+from repro.util import block_spec, clamp_block, wrap_block
 
 
 def _neighbour_index_map(di: int, gm: int, bc: str):
     """Block index map selecting the horizontal (0, di) neighbour tile."""
-    move = _wrap if bc == "periodic" else _clamp
+    move = wrap_block if bc == "periodic" else clamp_block
 
     def index_map(b, i):
         return (b, move(i + di, gm) if di else i)
@@ -141,18 +134,18 @@ def stencil1d_batch_pallas(
 
     dis = (-1, 0, 1) if hm > 0 else (0,)
     in_specs = [
-        pl.BlockSpec((tb, tm), _neighbour_index_map(di, gm, bc)) for di in dis
+        block_spec((tb, tm), _neighbour_index_map(di, gm, bc)) for di in dis
     ]
     operands = [data] * len(dis)
 
     # coefficients: whole (small) array in VMEM for every program
-    in_specs.append(pl.BlockSpec(coeffs.shape, lambda b, i: (0,) * coeffs.ndim))
+    in_specs.append(block_spec(coeffs.shape, lambda b, i: (0,) * coeffs.ndim))
     operands.append(coeffs)
 
     if bc == "np":
         if out_init is None:
             out_init = jnp.zeros_like(data)
-        in_specs.append(pl.BlockSpec((tb, tm), lambda b, i: (b, i)))
+        in_specs.append(block_spec((tb, tm), lambda b, i: (b, i)))
         operands.append(out_init)
 
     kernel = functools.partial(
@@ -171,7 +164,7 @@ def stencil1d_batch_pallas(
         kernel,
         grid=(gb, gm),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tb, tm), lambda b, i: (b, i)),
+        out_specs=block_spec((tb, tm), lambda b, i: (b, i)),
         out_shape=jax.ShapeDtypeStruct((B, M), data.dtype),
         interpret=interpret,
     )(*operands)

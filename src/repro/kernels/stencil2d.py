@@ -29,19 +29,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ref import weighted_point_fn
-
-
-def _wrap(i, n):
-    return jnp.remainder(i, n).astype(jnp.int32)
-
-
-def _clamp(i, n):
-    return jnp.clip(i, 0, n - 1).astype(jnp.int32)
+from repro.util import block_spec, clamp_block, wrap_block
 
 
 def _neighbour_index_map(dj: int, di: int, gy: int, gx: int, bc: str):
     """Block index map selecting the (dj, di) neighbour tile."""
-    move = _wrap if bc == "periodic" else _clamp
+    move = wrap_block if bc == "periodic" else clamp_block
 
     def index_map(j, i):
         jj = move(j + dj, gy) if dj else j
@@ -189,20 +182,20 @@ def stencil2d_pallas(
     for dj in djs:
         for di in dis:
             in_specs.append(
-                pl.BlockSpec(
+                block_spec(
                     (ty, tx), _neighbour_index_map(dj, di, gy, gx, bc)
                 )
             )
             operands.append(data)
 
     # coefficients: whole (small) array in VMEM for every program
-    in_specs.append(pl.BlockSpec(coeffs.shape, lambda j, i: (0,) * coeffs.ndim))
+    in_specs.append(block_spec(coeffs.shape, lambda j, i: (0,) * coeffs.ndim))
     operands.append(coeffs)
 
     if bc == "np":
         if out_init is None:
             out_init = jnp.zeros_like(data)
-        in_specs.append(pl.BlockSpec((ty, tx), lambda j, i: (j, i)))
+        in_specs.append(block_spec((ty, tx), lambda j, i: (j, i)))
         operands.append(out_init)
 
     kernel = functools.partial(
@@ -227,7 +220,7 @@ def stencil2d_pallas(
         kernel,
         grid=(gy, gx),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((ty, tx), lambda j, i: (j, i)),
+        out_specs=block_spec((ty, tx), lambda j, i: (j, i)),
         out_shape=jax.ShapeDtypeStruct((ny, nx), data.dtype),
         interpret=interpret,
     )(*operands)

@@ -22,14 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ref import weighted_point_fn
-
-
-def _wrap(i, n):
-    return jnp.remainder(i, n).astype(jnp.int32)
-
-
-def _clamp(i, n):
-    return jnp.clip(i, 0, n - 1).astype(jnp.int32)
+from repro.util import block_spec, clamp_block, wrap_block
 
 
 def _kernel(
@@ -85,8 +78,10 @@ def _kernel(
                 band, (z0, y0, 0), (z0 + tz, y0 + ty, nx)
             )
             for b in range(lf + rt + 1):
-                # x-halo via in-VMEM roll on the full row
-                windows.append(jnp.roll(sub, lf - b, axis=2))
+                # x-halo via in-VMEM roll on the full row (a zero shift
+                # is no roll: Mosaic refuses the empty slice it makes)
+                shift = lf - b
+                windows.append(jnp.roll(sub, shift, axis=2) if shift else sub)
     val = point_fn(windows, coeffs)
 
     if bc == "np":
@@ -129,7 +124,7 @@ def stencil3d_pallas(
         raise ValueError("halo exceeds tile")
     gz, gy = nz // tz, ny // ty
 
-    move = _wrap if bc == "periodic" else _clamp
+    move = wrap_block if bc == "periodic" else clamp_block
 
     def spec(dz, dy):
         def index_map(k, j):
@@ -137,19 +132,19 @@ def stencil3d_pallas(
             jj = move(j + dy, gy) if dy else j
             return (kk, jj, 0)
 
-        return pl.BlockSpec((tz, ty, nx), index_map)
+        return block_spec((tz, ty, nx), index_map)
 
     need_z, need_y = hz > 0, hy > 0
     dzs = (-1, 0, 1) if need_z else (0,)
     dys = (-1, 0, 1) if need_y else (0,)
     in_specs = [spec(dz, dy) for dz in dzs for dy in dys]
     operands = [data] * len(in_specs)
-    in_specs.append(pl.BlockSpec(coeffs.shape, lambda k, j: (0,) * coeffs.ndim))
+    in_specs.append(block_spec(coeffs.shape, lambda k, j: (0,) * coeffs.ndim))
     operands.append(coeffs)
     if bc == "np":
         if out_init is None:
             out_init = jnp.zeros_like(data)
-        in_specs.append(pl.BlockSpec((tz, ty, nx), lambda k, j: (k, j, 0)))
+        in_specs.append(block_spec((tz, ty, nx), lambda k, j: (k, j, 0)))
         operands.append(out_init)
 
     return pl.pallas_call(
@@ -159,7 +154,7 @@ def stencil3d_pallas(
         ),
         grid=(gz, gy),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tz, ty, nx), lambda k, j: (k, j, 0)),
+        out_specs=block_spec((tz, ty, nx), lambda k, j: (k, j, 0)),
         out_shape=jax.ShapeDtypeStruct((nz, ny, nx), data.dtype),
         interpret=interpret,
     )(*operands)

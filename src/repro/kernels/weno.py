@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ref import _weno5_phi
+from repro.util import block_spec, wrap_block
 
 _H = 3  # WENO5 halo
 
@@ -82,21 +83,20 @@ def weno5_advect_pallas(
         raise ValueError("tile smaller than WENO halo")
     gy, gx = ny // ty, nx // tx
 
-    wrap = lambda k, n: jnp.remainder(k, n).astype(jnp.int32)  # noqa: E731
     specs = [
-        pl.BlockSpec((ty, tx), lambda j, i: (j, i)),  # centre
-        pl.BlockSpec((ty, tx), lambda j, i: (j, wrap(i - 1, gx))),  # left
-        pl.BlockSpec((ty, tx), lambda j, i: (j, wrap(i + 1, gx))),  # right
-        pl.BlockSpec((ty, tx), lambda j, i: (wrap(j - 1, gy), i)),  # up
-        pl.BlockSpec((ty, tx), lambda j, i: (wrap(j + 1, gy), i)),  # down
-        pl.BlockSpec((ty, tx), lambda j, i: (j, i)),  # u
-        pl.BlockSpec((ty, tx), lambda j, i: (j, i)),  # v
+        block_spec((ty, tx), lambda j, i: (j, i)),  # centre
+        block_spec((ty, tx), lambda j, i: (j, wrap_block(i - 1, gx))),  # left
+        block_spec((ty, tx), lambda j, i: (j, wrap_block(i + 1, gx))),  # right
+        block_spec((ty, tx), lambda j, i: (wrap_block(j - 1, gy), i)),  # up
+        block_spec((ty, tx), lambda j, i: (wrap_block(j + 1, gy), i)),  # down
+        block_spec((ty, tx), lambda j, i: (j, i)),  # u
+        block_spec((ty, tx), lambda j, i: (j, i)),  # v
     ]
     return pl.pallas_call(
         functools.partial(_weno_kernel, dx=dx, dy=dy, ty=ty, tx=tx),
         grid=(gy, gx),
         in_specs=specs,
-        out_specs=pl.BlockSpec((ty, tx), lambda j, i: (j, i)),
+        out_specs=block_spec((ty, tx), lambda j, i: (j, i)),
         out_shape=jax.ShapeDtypeStruct((ny, nx), q.dtype),
         interpret=interpret,
     )(q, q, q, q, q, u, v)

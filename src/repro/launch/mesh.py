@@ -10,26 +10,11 @@ from __future__ import annotations
 
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - pinned jax 0.4.x
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` across jax versions: pass explicit Auto axis types
-    when the installed jax knows them, plain construction otherwise (every
-    axis is implicitly Auto there — identical semantics)."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-            )
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

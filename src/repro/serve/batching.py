@@ -139,12 +139,15 @@ def quantize_batch(b: int, max_batch: int) -> int:
     return min(p, max_batch) if b <= max_batch else b
 
 
+# The steps run as loop iterations, not unrolled: XLA would otherwise fuse
+# one step's arithmetic into the next and round differently (1 ulp on an
+# AVX-512 host) from the sequential one-step Computes the engine matches.
+
+
 @functools.partial(jax.jit, static_argnums=(2,))
 def _run_stacked_batch1d(plan, stack, steps: int):
     """One launch for a stacked (B, M) bucket of rank-1 requests."""
-    for _ in range(steps):
-        stack = _api.compute(plan, stack)
-    return stack
+    return jax.lax.fori_loop(0, steps, lambda _, s: _api.compute(plan, s), stack)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -152,11 +155,9 @@ def _run_stacked_stencil(plan, stack, steps: int):
     """One vmapped launch for a stacked bucket of 2D/3D stencil requests."""
 
     def one(field):
-        for _ in range(steps):
-            field = _api.compute(plan, field)
-        return field
+        return _api.compute(plan, field)
 
-    return jax.vmap(one)(stack)
+    return jax.lax.fori_loop(0, steps, lambda _, s: jax.vmap(one)(s), stack)
 
 
 def execute_bucket(plan, kind: str, fields, steps: int, *, max_batch: int = 64):

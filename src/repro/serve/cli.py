@@ -134,6 +134,9 @@ def main(argv=None) -> int:
                     help="also write stats as JSON")
     args = ap.parse_args(argv)
 
+    from repro.util import init_compile_cache
+
+    init_compile_cache()
     jax.config.update("jax_enable_x64", True)  # the library's f64 convention
 
     from repro.serve.engine import ServeEngine

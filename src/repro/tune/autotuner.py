@@ -20,7 +20,7 @@ by integer factors, which is all Create-time selection needs.
 
 Module-level :data:`stats` counts measurement runs and cache hits/misses
 so tests (and curious users) can verify that a cached Create performs no
-measurement work at all.
+measurement work at all, and lists every candidate that raised.
 """
 
 from __future__ import annotations
@@ -66,6 +66,10 @@ class TuneStats:
     cache_misses: int = 0
     tuned: int = 0  # autotune() calls that produced a winner
     pruned: int = 0  # candidates skipped by the analytic cost prior
+    # candidates dropped because building or running them raised:
+    # (kernel, config, exception) — a kernel the compiler refuses shows
+    # up here instead of vanishing from the race
+    dropped: list = dataclasses.field(default_factory=list)
 
 
 stats = TuneStats()
@@ -78,6 +82,7 @@ def reset_stats() -> TuneStats:
     stats.cache_misses = 0
     stats.tuned = 0
     stats.pruned = 0
+    stats.dropped.clear()
     return stats
 
 
@@ -125,8 +130,10 @@ def autotune(
     Returns the winning config dict.  ``mode='off'`` (or an empty/single
     candidate list) short-circuits to ``default`` (or the first
     candidate) without any measurement.  Infeasible candidates —
-    ``build`` returning ``None`` or the timed call raising — are skipped;
-    if every candidate is infeasible the default is returned.
+    ``build`` returning ``None`` — are skipped; a candidate whose build
+    or timed call raises is skipped and recorded with its exception in
+    ``stats.dropped``.  If every candidate is skipped the default is
+    returned.
 
     ``prior`` is an optional analytic scorer ``config -> predicted time
     proxy`` (see :mod:`repro.tune.prior`): candidates predicted far
@@ -170,13 +177,11 @@ def autotune(
     for config in to_measure:
         try:
             fn = build(dict(config))
-        except Exception:  # noqa: BLE001 — infeasible candidate
-            continue
-        if fn is None:
-            continue
-        try:
+            if fn is None:  # declared infeasible
+                continue
             us = measure(fn, *args)
-        except Exception:  # noqa: BLE001 — candidate fails at run time
+        except Exception as e:  # noqa: BLE001 — fails to build/compile/run
+            stats.dropped.append((kernel, dict(config), e))
             continue
         if us < best_us:
             best, best_us = dict(config), us

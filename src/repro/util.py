@@ -3,10 +3,61 @@
 from __future__ import annotations
 
 import math
+import os
 import warnings
 from collections.abc import Sequence
+from pathlib import Path
 
+import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+#: The compile cache's place when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: fixed, because the path is part of the cache key.
+REPO_JAX_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets nothing.  Otherwise the cache goes to ``<repo>/.jax_cache``.
+    Called by the command-line entry points only, never on import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_JAX_CACHE))
+    return str(REPO_JAX_CACHE)
+
+
+def block_spec(block_shape, index_map, **kwargs):
+    """A Pallas ``BlockSpec`` whose index map returns int32 block indices.
+
+    With ``jax_enable_x64`` on, the Python ints of an index map (the
+    default one included) become int64, which Mosaic cannot lower; the
+    TPU compile refuses the kernel."""
+
+    def index_map32(*grid):
+        return tuple(
+            jax.lax.convert_element_type(i, jnp.int32) for i in index_map(*grid)
+        )
+
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(block_shape, index_map32, **kwargs)
+
+
+def wrap_block(i, n: int):
+    """Periodic neighbour block index ``i mod n`` for ``i >= -n``, in int32
+    arithmetic (``jnp.remainder`` goes through int64 under x64)."""
+    return jax.lax.rem(i + np.int32(n), np.int32(n))
+
+
+def clamp_block(i, n: int):
+    """Clamped neighbour block index in ``[0, n)``, in int32 arithmetic."""
+    return jax.lax.clamp(np.int32(0), i, np.int32(n - 1))
 
 
 def warn_deprecated(old: str, new: str, *, stacklevel: int = 3) -> None:

@@ -75,3 +75,38 @@ class TestBenchmarkHarness:
         names = [b[0] for b in brun.BENCHMARKS]
         assert "stencil_sweep" in names
         assert "cahn_hilliard_step" in names
+
+
+class TestCompileCache:
+    """Entry points place JAX's persistent compile cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else at a fixed ``<repo>/.jax_cache``."""
+
+    def _run(self, monkeypatch, env):
+        import jax
+
+        from repro import util
+
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            return util.init_compile_cache(), jax.config.jax_compilation_cache_dir
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        import jax
+
+        before = jax.config.jax_compilation_cache_dir
+        where, config = self._run(monkeypatch, str(tmp_path))
+        assert where == str(tmp_path)
+        assert config == before  # nothing set in code
+
+    def test_default_is_the_repo_cache(self, monkeypatch):
+        from pathlib import Path
+
+        where, config = self._run(monkeypatch, None)
+        repo = Path(__file__).resolve().parents[1]
+        assert where == config == str(repo / ".jax_cache")

@@ -84,6 +84,37 @@ class TestCacheHitMiss:
         assert best == {"w": 7}
         assert T.stats.measure_runs == 0
 
+    def test_failing_candidate_is_recorded_not_hidden(self, cache):
+        # an injected kernel failure at the Pallas dispatch drops that
+        # candidate from the race, with its exception in the stats
+        from repro.kernels import ops
+        from repro.runtime import chaos
+
+        x = jnp.ones((16, 16), jnp.float32)
+        coeffs = jnp.ones((5,), jnp.float32)
+
+        def build(cfg):
+            return jax.jit(
+                lambda a: ops.stencil_apply(
+                    a, coeffs, left=1, right=1, top=1, bottom=1,
+                    backend=cfg["backend"],
+                )
+            )
+
+        cands = [{"backend": "pallas"}, {"backend": "jnp"}]
+        plan = chaos.FaultPlan().add("pallas.dispatch", "backend_error", at=1)
+        with chaos.injected(plan):
+            best = T.autotune(
+                "toy_dispatch", cands, build, (x,), mode="force",
+                shape=(16, 16), dtype=jnp.float32,
+            )
+        assert best == {"backend": "jnp"}
+        [(kernel, config, err)] = T.stats.dropped
+        assert (kernel, config) == ("toy_dispatch", {"backend": "pallas"})
+        assert isinstance(err, chaos.BackendError)
+        T.reset_stats()
+        assert T.stats.dropped == []
+
     def test_stale_cache_entry_not_in_candidates_is_miss(self, cache):
         key = T.tune_key("toy", extra=None, **KEY_KW)
         cache.put(key, {"w": 999})  # config no longer offered
