@@ -39,10 +39,13 @@ The package is organised as a production framework:
   :mod:`repro.runtime`    — training substrate (optimizers, pipelines,
   fault-tolerant checkpointing, sharding rules).
 - :mod:`repro.launch`     — meshes, dry-run driver, train/serve entry points.
+- :mod:`repro.obs`        — stage scopes, host spans and compile counters
+  (``custen.*`` names in a profile; :func:`repro.obs.counters`).
 """
 
 __version__ = "2.0.0"  # tracks cuSten's published version
 
+from repro import obs  # registers the compile counters' listeners
 from repro.api import (
     OperatorDef,
     compute,

@@ -58,6 +58,7 @@ from collections.abc import Callable
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import adi as _adi
 from repro.core import stencil as _stencil
 from repro.kernels.penta import (
@@ -365,6 +366,7 @@ def _resolve_direction(rank: int, mode: str | None, wndim: int | None):
     )
 
 
+@obs.span("create")
 def create(
     weights_or_fn,
     shape,

@@ -41,6 +41,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.stencil import StencilBatch1D
 from repro.kernels import spectral
 from repro.util import deprecated_shim
@@ -135,6 +136,7 @@ class ADIOperator:
         cfg = cfg or {}
         return cfg.get("backend", self.backend), cfg.get("unroll", 1), cfg
 
+    @obs.stage("adi.x")
     def solve_x(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_x w = rhs along the x (last) axis of an (ny, nx) field —
         row layout, transpose-free."""
@@ -167,6 +169,7 @@ class ADIOperator:
             self.fac_x, rhs, backend=backend, tb=cfg.get("tb"), unroll=unroll
         )
 
+    @obs.stage("adi.y")
     def solve_y(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_y v = rhs along the y (first) axis of an (ny, nx) field —
         column layout, native."""
@@ -458,6 +461,7 @@ class ADIOperator3D:
             max_tile_bytes=self.max_tile_bytes,
         )
 
+    @obs.stage("adi.x")
     def solve_x(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_x w = rhs along the x (last) axis — row layout on the
         flattened (nz*ny, nx) batch, transpose-free."""
@@ -490,6 +494,7 @@ class ADIOperator3D:
             )
         return out.reshape(rhs.shape)
 
+    @obs.stage("adi.y")
     def solve_y(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_y v = rhs along the y (middle) axis — plane layout,
         transpose-free."""
@@ -517,6 +522,7 @@ class ADIOperator3D:
             self.fac_y, rhs, backend=backend, tn=cfg.get("tn"), unroll=unroll
         )
 
+    @obs.stage("adi.z")
     def solve_z(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_z u = rhs along the z (first) axis — column layout on
         the (nz, ny*nx) reshape, transpose-free."""

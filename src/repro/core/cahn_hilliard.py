@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import api as _api
+from repro import obs
 from repro.core import metrics as _metrics
 from repro.runtime import chaos as _chaos
 from repro.core.adi import (
@@ -239,6 +240,7 @@ class CahnHilliardADI:
         )
 
     # -- explicit RHS of the full scheme (eq. 2a) --------------------------
+    @obs.stage("ch.rhs")
     def rhs(self, c_n: jnp.ndarray, c_nm1: jnp.ndarray) -> jnp.ndarray:
         cfg = self.cfg
         if cfg.rhs_mode == "fused":
@@ -375,6 +377,7 @@ class CahnHilliardADI:
         return best["streams"], best.get("chunk_rows")
 
     # -- fused explicit RHS + transpose-free x-sweep (the hot loop) ---------
+    @obs.stage("adi.x")  # the x sweep, with the RHS folded in
     def _fused_xsweep(self, c_n: jnp.ndarray, c_nm1: jnp.ndarray) -> jnp.ndarray:
         """``L_x^{-1} rhs(c_n, c_nm1)`` in one fused pass — the RHS feeds
         the row-layout x-sweep in its native layout, streamed when the
@@ -428,10 +431,12 @@ class CahnHilliardADI:
         else:
             w = self.op_full.solve_x(self.rhs(c_n, c_nm1))
         v = self.op_full.solve_y(w)
-        c_np1 = 2.0 * c_n - c_nm1 + v
+        with obs.stage("ch.update"):
+            c_np1 = 2.0 * c_n - c_nm1 + v
         return c_np1, c_n
 
     # -- bootstrap step (eq. 3) ---------------------------------------------
+    @obs.stage("ch.bootstrap")
     def initial_step(self, c0: jnp.ndarray) -> jnp.ndarray:
         cfg = self.cfg
         half = 0.5 * cfg.dt
@@ -532,8 +537,9 @@ def ch_evolve(
     donation.  Returns ``(c_final, history)`` with history a list of
     ``(step, metrics_fn(c))`` every ``save_every`` steps.
     """
-    c0 = jnp.array(c0)  # private copy: the carry buffers get donated
-    c1 = solver.initial_step(c0)
+    with obs.span("evolve.bootstrap"):
+        c0 = jnp.array(c0)  # private copy: the carry buffers get donated
+        c1 = solver.initial_step(c0)
     # the Swap: the freshly computed field becomes the carry's "current"
     carry = _api.swap((c0, c1))
     chunk = save_every if save_every else n_steps
@@ -541,16 +547,18 @@ def ch_evolve(
     done = 1  # initial step counts as step 1
     while done < n_steps + 1:
         todo = min(chunk, n_steps + 1 - done)
-        # chaos hook at the chunk boundary: 'crash' kills the driver here
-        # (checkpoint/restart territory), 'nan' poisons the carry so the
-        # chunk blows up — both consumed by runtime/resilient.py's guard
-        fault = _chaos.fire("evolve.step", step=done)
-        if fault is not None and fault.kind == "nan":
-            carry = (carry[0].at[(0,) * carry[0].ndim].set(fault.value), carry[1])
-        carry = solver.make_evolve(todo)(*carry)
+        with obs.span("evolve.chunk"):
+            # chaos hook at the chunk boundary: 'crash' kills the run here
+            # (checkpoint/restart territory), 'nan' poisons the carry so the
+            # chunk blows up — both consumed by runtime/resilient.py's guard
+            fault = _chaos.fire("evolve.step", step=done)
+            if fault is not None and fault.kind == "nan":
+                carry = (carry[0].at[(0,) * carry[0].ndim].set(fault.value), carry[1])
+            carry = solver.make_evolve(todo)(*carry)
         done += todo
         if metrics_fn is not None:
-            history.append((done, metrics_fn(carry[0])))
+            with obs.span("evolve.metrics"):
+                history.append((done, metrics_fn(carry[0])))
     return carry[0], history
 
 

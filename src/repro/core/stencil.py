@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops
 from repro.kernels.ref import weighted_point_fn
 from repro.util import deprecated_shim
@@ -219,6 +220,7 @@ class PlanCore:
         return spectral.apply_symbol(data, self.symbol, self._fft_axes())
 
     # -- Compute ----------------------------------------------------------
+    @obs.stage("stencil")
     def apply(
         self, data: jnp.ndarray, out_init: jnp.ndarray | None = None
     ) -> jnp.ndarray:

@@ -175,3 +175,24 @@ def test_float64_stays_off_mosaic(tpu_dispatch):
     rhs = jnp.ones((M, 128), jnp.float64)
     with pytest.raises(ValueError, match="penta sweep cannot run on this TPU"):
         penta_solve_factored(fac, rhs, backend="pallas", interpret=False)
+
+
+def test_ch_evolve_kernels_carry_their_sweep_stage(one_chip, tpu_dispatch):
+    # every Pallas kernel of the CH hot loop is charged to its ADI sweep:
+    # the fused RHS + x-sweep to custen.adi.x, the column penta to adi.y
+    import re
+
+    from repro.core.cahn_hilliard import CahnHilliardADI, CHConfig
+
+    n = 256
+    h = 2 * np.pi / n
+    solver = CahnHilliardADI(CHConfig(
+        nx=n, ny=n, dt=0.1 * h**4 / (0.6 * 0.01), dtype="float32",
+        backend="auto"))
+    c = jax.ShapeDtypeStruct((n, n), F32, sharding=one_chip)
+    text = solver.make_evolve(2).lower(c, c).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    stages = [re.search(r'op_name="[^"]*?custen\.(adi\.[xy])/', k) for k in kernels]
+    assert kernels and all(stages), kernels
+    assert {s.group(1) for s in stages} == {"adi.x", "adi.y"}
