@@ -9,8 +9,9 @@ happens once at Create time (:class:`ADIOperator`); each Compute is a batched
 banded substitution.  Both sweeps are **transpose-free**: the y-sweep runs
 the column-layout substitution (systems along axis 0, batch on lanes) and
 the x-sweep the row-layout variant (batch along axis 0, recurrence along
-lanes) — both factored once at Create time, so no per-step interleaving
-transpose remains anywhere.
+axis 1) — both factored once at Create time, so no per-step interleaving
+transpose of the field passes through HBM (the Pallas row kernel
+transposes 128-lane chunks in VMEM only).
 
 The *explicit* side of each sweep is the same batched-1D picture: a purely
 directional stencil applied to every grid line at once.

@@ -12,9 +12,10 @@ Kernels:
   ``1DBatch`` family): batch tiled over the grid, M on the lanes, halos
   along M only.
 - ``penta``      — batched pentadiagonal substitution (cuPentBatch) in both
-  layouts (column: batch on lanes; row: recurrence on lanes, the
-  transpose-free x-sweep), plus Create-time LU factorisation and rank-4
-  Woodbury cyclic closure evaluated as broadcast FMAs.
+  layouts (column: batch on lanes; row: recurrence along the rows, the
+  x-sweep, whose lane chunks the kernel transposes in VMEM so every
+  layout's recurrence walks sublanes), plus Create-time LU factorisation
+  and rank-4 Woodbury cyclic closure evaluated as broadcast FMAs.
 - ``weno``       — WENO5 upwind advection RHS (the 2d_xyADVWENO_p variant).
 - ``fused_ch``   — beyond-paper: the whole Cahn–Hilliard explicit RHS fused
   into one VMEM pass, and the RHS + implicit x-sweep fused into a single

@@ -16,9 +16,10 @@ ADI step.  The oracle is :func:`repro.kernels.ref.ch_rhs_ref`.
 :func:`ch_rhs_xsweep_pallas` goes one step further — the ADI hot loop's
 full explicit half *plus* the implicit x-sweep in one ``pallas_call``: the
 RHS tile is assembled in VMEM and immediately consumed by the row-layout
-(lane-recurrence) pentadiagonal substitution of
-:mod:`repro.kernels.penta`, Woodbury closure included.  The RHS never
-round-trips through HBM and no transpose appears anywhere.
+pentadiagonal substitution of :mod:`repro.kernels.penta`, Woodbury
+closure included.  The RHS never round-trips through HBM and no
+transpose of the field passes through HBM: the band's 128-lane chunks
+are transposed in VMEM so the recurrence runs on sublanes.
 """
 
 from __future__ import annotations
@@ -33,9 +34,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.penta import (
     VMEM_LIMIT_BYTES,
     _fac_table,
+    _pad,
     _smem_table_spec,
     rows_woodbury_correct,
-    sweep_refs,
+    sweep_rows_refs,
     tpu_sweep_problem,
     woodbury_rows,
 )
@@ -151,8 +153,8 @@ def ch_rhs_pallas(
 
 
 # ---------------------------------------------------------------------------
-# Fused RHS + transpose-free x-sweep: the whole eq.-(2a) explicit half and
-# the L_x solve in one pallas_call (full-width row-band tiles, gx == 1)
+# Fused RHS + x-sweep: the whole eq.-(2a) explicit half and the L_x solve
+# in one pallas_call (full-width row-band tiles, gx == 1)
 # ---------------------------------------------------------------------------
 
 
@@ -161,10 +163,10 @@ def _ch_xsweep_kernel(
 ):
     # refs: c_n's hb-row halo block above, (ty, nx) row band, halo block
     #       below; the same three of c_nm1; the (5, nx) SMEM factor table;
-    #       W^T (4, nx); out (ty, nx)
+    #       W^T (4, nx); out (ty, nx); the (nx, ty) VMEM scratch
     cn_tiles = [r[...] for r in refs[:3]]
     cm_tiles = [r[...] for r in refs[3:6]]
-    f_ref, wt_ref, o_ref = refs[6:]
+    f_ref, wt_ref, o_ref, t_ref = refs[6:]
 
     def assemble(above, mid, below):
         band = jnp.concatenate([above[hb - _H :, :], mid, below[:_H, :]], axis=0)
@@ -187,29 +189,35 @@ def _ch_xsweep_kernel(
     nonlin = (2.0 / 3.0) * D * dt * _laplacian(sh_nl, inv_h2)
     o_ref[...] = (lin + hyper + nonlin).astype(o_ref.dtype)
 
-    # Row-layout substitution in place (the RHS never leaves VMEM), then
-    # the Woodbury closure — both shared with kernels/penta.py so the
-    # fused kernel stays in lockstep with the standalone solve.
-    sweep_refs(f_ref, o_ref, axis=1)
+    # Row-layout substitution in place (the RHS never leaves VMEM; its
+    # lane chunks are transposed through t_ref so the recurrence walks
+    # sublanes), then the Woodbury closure — both shared with
+    # kernels/penta.py so the fused kernel stays in lockstep with the
+    # standalone solve.
+    sweep_rows_refs(f_ref, o_ref, o_ref, t_ref)
     o_ref[...] = rows_woodbury_correct(o_ref[...], wt_ref[...]).astype(
         o_ref.dtype
     )
 
 
 # VMEM the fused kernel's row band may take, under VMEM_LIMIT_BYTES: the
-# compiler also keeps Mosaic's own relayout copies there.
-XSWEEP_VMEM_BUDGET = 24 * 2**20
+# compiler also keeps Mosaic's own relayout copies there.  A taller band
+# halves the serial chain of the x recurrence, so the budget admits the
+# tallest band that AOT compiles for a v5e (ty = 128 at nx = 4096, about
+# 41 MiB by this estimate; ty = 256 there, about 80 MiB, does not).
+XSWEEP_VMEM_BUDGET = 48 * 2**20
 
 
 def xsweep_vmem_bytes(ty: int, nx: int, itemsize: int = 4) -> int:
     """Estimated VMEM of one fused-kernel grid step: the double-buffered
-    row bands and halo blocks of both fields and the output band, plus
-    about a dozen (ty+4, nx+4) band temporaries (cn, cm, cbar, nl and the
-    stencil terms), each padded to the (8, 128) tile."""
-    pad = lambda n, m: -(-n // m) * m  # noqa: E731
+    row bands and halo blocks of both fields and the output band, about
+    a dozen (ty+4, nx+4) band temporaries (cn, cm, cbar, nl and the
+    stencil terms), and the (nx, ty) transpose scratch of the sweep, each
+    padded to the (8, 128) tile."""
     blocks = 2 * (2 * (ty + 16) + ty) * nx
-    temps = 12 * pad(ty + 2 * _H, 8) * pad(nx + 2 * _H, 128)
-    return (blocks + temps) * itemsize
+    temps = 12 * _pad(ty + 2 * _H, 8) * _pad(nx + 2 * _H, 128)
+    scratch = _pad(nx, 8) * _pad(ty, 128)
+    return (blocks + temps + scratch) * itemsize
 
 
 def xsweep_tile(ny: int, nx: int, itemsize: int = 4) -> int:
@@ -224,7 +232,10 @@ def xsweep_tile(ny: int, nx: int, itemsize: int = 4) -> int:
 
 def xsweep_tpu_problem(ny: int, nx: int, ty: int, dtype) -> str | None:
     """Why :func:`ch_rhs_xsweep_pallas` cannot be compiled for a TPU with
-    ``ty``-row bands, or ``None`` when it can."""
+    ``ty``-row bands, or ``None`` when it can: the band step, transpose
+    scratch included, fits :data:`XSWEEP_VMEM_BUDGET`, and the row-layout
+    sweep's own rules hold (``nx`` a multiple of 8, transposed in chunks
+    of 128 lanes or of all of ``nx``)."""
     if ty < _H:
         return f"row tile {ty} is below the halo {_H}"
     if xsweep_vmem_bytes(ty, nx, jnp.dtype(dtype).itemsize) > XSWEEP_VMEM_BUDGET:
@@ -254,8 +265,9 @@ def ch_rhs_xsweep_pallas(
     """One ``pallas_call`` computing ``L_x^{-1} rhs(c_n, c_nm1)``.
 
     ``fac_x`` is a :class:`repro.kernels.penta.CyclicPentaFactors` of
-    length ``nx``.  Tiles are full-width row bands (the lane recurrence
-    needs the whole x extent in VMEM); the grid walks the y axis.  The
+    length ``nx``.  Tiles are full-width row bands (the x recurrence
+    needs the whole x extent in VMEM, transposed there into an
+    (nx, ty) scratch); the grid walks the y axis.  The
     y halos come from the 8-row blocks above and below the band (the
     whole band where ``ty`` is not a multiple of 8).
     """
@@ -290,6 +302,7 @@ def ch_rhs_xsweep_pallas(
         in_specs=in_specs,
         out_specs=band,
         out_shape=jax.ShapeDtypeStruct((ny, nx), c_n.dtype),
+        scratch_shapes=[pltpu.VMEM((nx, ty), c_n.dtype)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)

@@ -669,13 +669,14 @@ def ch_rhs_xsweep(
     backend: str = "auto", ty: int | None = None,
     interpret: bool | None = None, unroll: int = 1,
 ):
-    """Fused explicit RHS + transpose-free implicit x-sweep:
+    """Fused explicit RHS + implicit x-sweep:
     ``L_x^{-1} rhs(c_n, c_nm1)`` with ``fac_x`` the Create-time cyclic
     factors along x.  On TPU this is one ``pallas_call``
     (:func:`repro.kernels.fused_ch.ch_rhs_xsweep_pallas`); the jnp path
     composes the windowed RHS with the row-layout substitution — in both
     cases the RHS feeds the sweep in its native row layout with no
-    intermediate transpose.
+    transpose of the field in HBM (the Pallas kernel transposes 128-lane
+    chunks in VMEM, the jnp path none).
     """
     from repro.kernels.fused_ch import (
         ch_rhs_xsweep_pallas,

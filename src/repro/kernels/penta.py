@@ -19,8 +19,8 @@ cuSten/cuPentBatch split Create/Compute:
   each Compute is then one banded substitution + two tiny matmuls.
 
 Three substitution layouts are provided, so a full ADI step — 2D *or*
-3D — is **transpose-free** (every sweep consumes Create-time factors in
-its native layout):
+3D — moves **no transpose through HBM** (every sweep consumes Create-time
+factors in its native layout):
 
 - *column layout* (:func:`penta_solve_factored`): systems along axis 0
   (length M), batch along axis 1 — the y-sweep of an ``(ny, nx)`` field
@@ -28,11 +28,11 @@ its native layout):
   one.
 - *row layout* (:func:`penta_solve_factored_rows`): batch along axis 0,
   recurrence along axis 1 (TPU lanes) — the x-sweep, with no
-  interleaving transpose at all.  The Pallas variant carries two
-  previous *columns* in vector registers and strides the recurrence
-  across lanes; the jnp variant walks the lanes with a ``fori_loop`` of
-  dynamic column slices.  Reshaped to ``(nz*ny, nx)`` it is also the 3D
-  x-sweep.
+  interleaving transpose of the field.  The Pallas variant transposes
+  each block's 128-lane chunks into a VMEM scratch and runs the column
+  recurrence there, on sublanes; the jnp variant walks the lanes with a
+  ``fori_loop`` of dynamic column slices.  Reshaped to ``(nz*ny, nx)``
+  it is also the 3D x-sweep.
 - *plane layout* (:func:`penta_solve_factored_mid`): batch along axes 0
   and 2 of a ``(P, M, N)`` stack, recurrence along the *middle* axis —
   the y-sweep of a 3D field, where neither reshape nor transpose can
@@ -282,29 +282,29 @@ def _fac_table(fac: PentaFactors) -> jnp.ndarray:
     return jnp.stack([fac.sub, fac.low, fac.inv_mu, fac.al, fac.be])
 
 
-def _chunk(M: int, lanes: bool) -> int:
+def _chunk(M: int) -> int:
     """Recurrence steps per block: the largest divisor of ``M`` up to one
-    vreg extent (128 lanes along the last axis, 8 sublanes otherwise)."""
-    align = 128 if lanes else 8
-    return max(c for c in range(1, min(M, align) + 1) if M % c == 0)
+    vreg's 8 sublanes."""
+    return max(c for c in range(1, min(M, 8) + 1) if M % c == 0)
 
 
-def sweep_refs(f_ref, o_ref, *, axis: int) -> None:
-    """In-place banded substitution along ``axis`` of the Pallas ref
-    ``o_ref``, which holds the right-hand side on entry and the solution on
-    exit; ``f_ref`` is the (5, M) SMEM factor table.
+def sweep_refs(f_ref, o_ref) -> None:
+    """In-place banded substitution along the sublanes (the second-to-last
+    axis) of the Pallas ref ``o_ref``, which holds the right-hand side on
+    entry and the solution on exit; ``f_ref`` is the (5, M) SMEM factor
+    table.
 
-    The one recurrence of every layout: column and plane sweeps walk the
-    sublanes (axis 0 or 1 of a (1, M, tn) block), the row sweep and the
-    fused CH kernel walk the lanes (axis 1 of a (tb, M) block).  Mosaic
-    refuses dynamic single-lane accesses and unaligned single-row ones,
-    so the loop loads and stores whole aligned blocks of
-    :func:`_chunk` steps and runs the steps inside a block unrolled, on
-    static slices.
+    The one recurrence of every layout: column and plane sweeps run it on
+    their (1, M, tn) blocks, the row sweep and the fused CH kernel on an
+    (M, tb) VMEM scratch that :func:`sweep_rows_refs` transposes their
+    rows into.  Mosaic refuses unaligned single-row accesses, so the loop
+    loads and stores whole aligned blocks of :func:`_chunk` steps and runs
+    the steps inside a block unrolled, on static slices.
     """
     nd = len(o_ref.shape)
+    axis = nd - 2
     M = o_ref.shape[axis]
-    chunk = _chunk(M, lanes=axis == nd - 1)
+    chunk = _chunk(M)
     n = M // chunk
 
     def at(off):
@@ -355,11 +355,53 @@ def sweep_refs(f_ref, o_ref, *, axis: int) -> None:
     jax.lax.fori_loop(jnp.int32(0), jnp.int32(n), bwd, (zero, zero))
 
 
+def sweep_rows_refs(f_ref, r_ref, o_ref, t_ref) -> None:
+    """Row-layout substitution: every row of the (tb, M) ref ``r_ref`` is
+    one system, and the solutions go to ``o_ref`` (which may be
+    ``r_ref``).  The rows' lane chunks (one vreg's 128 lanes, or all of
+    ``M`` where 128 does not divide it) are transposed into the (M, tb)
+    VMEM scratch ``t_ref``, :func:`sweep_refs` walks its sublanes, and
+    the chunks are transposed back: the transposes stay in VMEM, and the
+    recurrence steps a whole row of the scratch at a time where stepping
+    along the lanes would select one lane per step."""
+    M = r_ref.shape[1]
+    c = 128 if M % 128 == 0 else M
+
+    def lanes(off):
+        return slice(None), pl.ds(off, c)
+
+    def rows(off):
+        return pl.ds(off, c), slice(None)
+
+    def move(src, src_at, dst, dst_at):
+        if c == M:  # one chunk, at a static offset
+            dst[...] = src[...].T
+            return
+
+        def body(k, carry):
+            off = pl.multiple_of(k * np.int32(c), c)
+            dst[dst_at(off)] = src[src_at(off)].T
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(M // c), body, None)
+
+    move(r_ref, lanes, t_ref, rows)
+    sweep_refs(f_ref, t_ref)
+    move(t_ref, rows, o_ref, lanes)
+
+
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def tpu_sweep_problem(M, batch, tile, dtype, *, lanes: bool) -> str | None:
     """Why the Pallas sweep cannot be compiled for a TPU at this shape, or
-    ``None`` when it can.  ``lanes`` says the recurrence runs along the
-    lanes (row layout); ``batch``/``tile`` are the tiled batch extent and
-    its block (rows of the row layout, lanes of the column/plane ones)."""
+    ``None`` when it can.  ``lanes`` says the systems lie along the lanes
+    (row layout: (tile, M) blocks, transposed in lane chunks of 128, or of
+    all of ``M`` where 128 does not divide it, into an (M, tile) scratch);
+    ``batch``/``tile`` are the tiled batch extent and its block (rows of
+    the row layout, lanes of the column/plane ones).  Every layout's
+    recurrence walks sublanes, so ``M`` is a multiple of 8."""
     if jnp.dtype(dtype).itemsize != 4:
         return f"Mosaic has no {jnp.dtype(dtype).name} sweep (float32 only)"
     if batch % tile:
@@ -367,11 +409,13 @@ def tpu_sweep_problem(M, batch, tile, dtype, *, lanes: bool) -> str | None:
     align = 8 if lanes else 128
     if tile % align and tile != batch:
         return f"batch tile {tile} is not a multiple of {align}"
-    if _chunk(M, lanes) not in (128 if lanes else 8, M):
-        return f"recurrence length {M} is not a multiple of {128 if lanes else 8}"
+    if _chunk(M) not in (8, M):
+        return f"recurrence length {M} is not a multiple of 8"
     if M > SMEM_MAX_M:
         return f"recurrence length {M} > {SMEM_MAX_M}: factors exceed SMEM"
-    if 4 * 4 * M * tile > VMEM_LIMIT_BYTES:  # in + out, double-buffered
+    # in + out, double-buffered, and the row layout's transpose scratch
+    scratch = M * _pad(tile, 128) if lanes else 0
+    if 4 * (4 * M * tile + scratch) > VMEM_LIMIT_BYTES:
         return f"a ({M}, {tile}) block exceeds the VMEM limit"
     return None
 
@@ -381,16 +425,18 @@ def _smem_table_spec(M: int):
     return block_spec((5, M), lambda *_: (0, 0), memory_space=pltpu.SMEM)
 
 
-def _solve_kernel(f_ref, r_ref, o_ref, *, axis):
+def _solve_kernel(f_ref, r_ref, o_ref):
     o_ref[...] = r_ref[...]
-    sweep_refs(f_ref, o_ref, axis=axis)
+    sweep_refs(f_ref, o_ref)
 
 
-def _pallas_sweep(fac, rhs, *, block, index_map, grid, interpret):
+def _pallas_sweep(
+    kernel, fac, rhs, *, block, index_map, grid, interpret, scratch_shapes=()
+):
     """One sweep ``pallas_call``: the recurrence runs along axis 1 of every
     ``block`` (the middle axis of a plane block, the lanes of a row block)."""
     return pl.pallas_call(
-        functools.partial(_solve_kernel, axis=1),
+        kernel,
         grid=grid,
         in_specs=[
             _smem_table_spec(rhs.shape[1]),
@@ -398,6 +444,7 @@ def _pallas_sweep(fac, rhs, *, block, index_map, grid, interpret):
         ],
         out_specs=block_spec(block, index_map),
         out_shape=jax.ShapeDtypeStruct(rhs.shape, rhs.dtype),
+        scratch_shapes=scratch_shapes,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(_fac_table(fac), rhs)
@@ -412,7 +459,8 @@ def _substitute_mid_pallas(
     if N % tn:
         raise ValueError(f"lane tile {tn} must divide N={N}")
     return _pallas_sweep(
-        fac, rhs, block=(1, M, tn), index_map=lambda p, i: (p, 0, i),
+        _solve_kernel, fac, rhs, block=(1, M, tn),
+        index_map=lambda p, i: (p, 0, i),
         grid=(P, N // tn), interpret=interpret,
     )
 
@@ -431,13 +479,15 @@ def _substitute_pallas(
 def _substitute_rows_pallas(
     fac: PentaFactors, rhs: jnp.ndarray, *, tb: int, interpret: bool
 ) -> jnp.ndarray:
-    """Row layout on (B, M): the recurrence walks the lanes of (tb, M)."""
+    """Row layout on (B, M): each (tb, M) block is transposed in VMEM
+    (:func:`sweep_rows_refs`) and the recurrence walks its sublanes."""
     B, M = rhs.shape
     if B % tb:
         raise ValueError(f"batch tile {tb} must divide B={B}")
     return _pallas_sweep(
-        fac, rhs, block=(tb, M), index_map=lambda i: (i, 0),
+        sweep_rows_refs, fac, rhs, block=(tb, M), index_map=lambda i: (i, 0),
         grid=(B // tb,), interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((M, tb), rhs.dtype)],
     )
 
 
@@ -514,7 +564,7 @@ def penta_solve_factored_rows(
 ) -> jnp.ndarray:
     """Row-layout solve: ``rhs`` is (B, M) (or (M,)), each *row* one system.
 
-    The transpose-free x-sweep — same factors as
+    The x-sweep, with no transpose of the field in HBM — same factors as
     :func:`penta_solve_factored`, recurrence along axis 1.
     """
     from repro.kernels import ops
@@ -647,7 +697,8 @@ def cyclic_penta_solve_factored_rows(
     unroll: int = 1,
 ) -> jnp.ndarray:
     """Row-layout Woodbury solve on a (B, M) rhs (each row one cyclic
-    system) — the transpose-free x-sweep of a periodic ADI step."""
+    system) — the x-sweep of a periodic ADI step, with no transpose of
+    the field in HBM."""
     squeeze = rhs.ndim == 1
     if squeeze:
         rhs = rhs[None, :]

@@ -1,10 +1,11 @@
 """The fused transpose-free ADI engine (PR-3 tentpole).
 
-Covers: row-layout (lane-recurrence) pentadiagonal substitution against the
-dense oracle in both backends, the fused RHS+x-sweep kernel, the
-zero-transpose property of the full Cahn–Hilliard step (checked on the
-jaxpr), streamed row-layout solves, the windowed RHS, the alignment-padded
-kernel dispatch for awkward extents, and the donated multi-step driver."""
+Covers: row-layout pentadiagonal substitution against the dense oracle in
+both backends (the Pallas one transposes lane chunks in VMEM), the fused
+RHS+x-sweep kernel, the zero-transpose property of the jnp Cahn–Hilliard
+step (checked on the jaxpr), streamed row-layout solves, the windowed RHS,
+the alignment-padded kernel dispatch for awkward extents, and the donated
+multi-step driver."""
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,10 @@ from repro.kernels.penta import (
     cyclic_penta_solve_factored_rows,
     hyperdiffusion_diagonals,
     penta_factor,
+    _substitute_rows_jnp,
+    _substitute_rows_pallas,
     penta_solve_factored_rows,
+    tpu_sweep_problem,
 )
 from repro.launch.stream import stream_ch_rhs_xsweep, stream_penta_solve_rows
 from repro.util import tolerance_for
@@ -97,6 +101,34 @@ class TestRowLayoutSubstitution:
         b = cyclic_penta_solve_factored_rows(fac, rhs, backend="jnp", unroll=4)
         np.testing.assert_array_equal(a, b)
 
+    @pytest.mark.parametrize("tb", [8, 16, 64, 128])
+    @pytest.mark.parametrize("m", [128, 256, 1024])
+    def test_pallas_rows_equal_jnp(self, m, tb):
+        # the Pallas row sweep transposes 128-lane chunks into a VMEM
+        # scratch and runs the column recurrence there: the same
+        # arithmetic in the same order as the jnp lane walk
+        rng = np.random.default_rng(m + tb)
+        fac = penta_factor(*hyperdiffusion_diagonals(m, 0.4))
+        rhs = _rand(rng, (2 * tb, m))
+        out = _substitute_rows_pallas(fac, rhs, tb=tb, interpret=True)
+        np.testing.assert_array_equal(out, _substitute_rows_jnp(fac, rhs))
+
+    @pytest.mark.parametrize(
+        "m, tb, problem",
+        [
+            (4096, 64, None),
+            (256, 128, None),
+            (200, 8, None),  # one chunk of all 200 lanes
+            (48, 16, None),
+            (12, 8, "not a multiple of 8"),
+            (4096, 12, "not a multiple of 8"),
+            (16384, 8, "exceed SMEM"),
+        ],
+    )
+    def test_row_sweep_tpu_shape_rules(self, m, tb, problem):
+        got = tpu_sweep_problem(m, 4 * tb, tb, np.float32, lanes=True)
+        assert (got is None) if problem is None else (problem in got)
+
     def test_non_divisible_row_tile_errors(self):
         fac = penta_factor(*hyperdiffusion_diagonals(16, 0.2))
         with pytest.raises(ValueError):
@@ -162,6 +194,44 @@ class TestFusedRHSXsweep:
             fac, R.ch_rhs_ref(a, b, **CH_KW), backend="jnp"
         )
         np.testing.assert_allclose(out, ref, **TOL_I)
+
+    @pytest.mark.parametrize("n", [32, 256])
+    @pytest.mark.parametrize("ty", [8, 16])
+    def test_pallas_xsweep_matches_jnp(self, ty, n):
+        # n = 32: one transposed chunk of all 32 lanes; 256: two of 128
+        rng = np.random.default_rng(ty + n)
+        a = _rand(rng, (32, n)) * 0.1
+        b = _rand(rng, (32, n)) * 0.1
+        fac = cyclic_penta_factor(*hyperdiffusion_diagonals(n, 0.4))
+        kw = dict(CH_KW, inv_h2=n * n / 10.0, inv_h4=(n * n / 10.0) ** 2)
+        out = ops.ch_rhs_xsweep(
+            a, b, fac, **kw, backend="pallas", interpret=True, ty=ty
+        )
+        ref = ops.ch_rhs_xsweep(a, b, fac, **kw, backend="jnp")
+        np.testing.assert_allclose(out, ref, **TOL_I)
+
+    def test_xsweep_tile_counts_the_transpose_scratch(self):
+        # the 4096^2 cell's band: its VMEM estimate holds the (nx, ty)
+        # transpose scratch, lanes padded to 128, and fits the budget,
+        # so backend='auto' takes the fused kernel there with 128-row
+        # bands, full vregs for the sublane recurrence
+        from repro.kernels.fused_ch import (
+            XSWEEP_VMEM_BUDGET,
+            xsweep_tile,
+            xsweep_tpu_problem,
+            xsweep_vmem_bytes,
+        )
+
+        n = 4096
+        ty = xsweep_tile(n, n)
+        assert ty == 128
+        pad = lambda v, m: -(-v // m) * m  # noqa: E731
+        blocks = 2 * (2 * (ty + 16) + ty) * n
+        temps = 12 * pad(ty + 4, 8) * pad(n + 4, 128)
+        scratch = n * pad(ty, 128)
+        assert xsweep_vmem_bytes(ty, n) == 4 * (blocks + temps + scratch)
+        assert xsweep_vmem_bytes(ty, n) <= XSWEEP_VMEM_BUDGET
+        assert xsweep_tpu_problem(n, n, ty, jnp.float32) is None
 
     def test_fused_step_has_zero_transposes(self):
         # the acceptance property: the full ADI Cahn-Hilliard step runs

@@ -146,6 +146,14 @@ def test_penta_rows(one_chip, tpu_dispatch):
     )
 
 
+def test_penta_rows_3d(one_chip, tpu_dispatch):
+    # the x-sweep of a 256^3 ADI step, reshaped to (nz*ny, nx)
+    _compile(
+        cyclic_penta_solve_factored_rows,
+        one_chip, _cyclic_factors(256), _s(65536, 256),
+    )
+
+
 def test_penta_plane(one_chip, tpu_dispatch):
     _compile(
         cyclic_penta_solve_factored_mid,
