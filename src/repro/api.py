@@ -281,17 +281,19 @@ def _laplacian_weights(ndim: int = 2, h: float = 1.0):
 
 
 def _biharmonic_weights(ndim: int = 2, h: float = 1.0):
-    """delta^4 in 1D; delta_x^2 + delta_y^2 + 2 delta_x delta_y in 2D
-    (paper eq. 4 — the Cahn–Hilliard hyperdiffusion stencil)."""
+    """delta^4 in 1D; in 2D and 3D the Laplacian applied twice: the 5x5
+    delta_x^2 + delta_y^2 + 2 delta_x delta_y of paper eq. (4) (the
+    Cahn–Hilliard hyperdiffusion stencil), and its 25-tap 5x5x5 analogue
+    with the three cross terms."""
     if ndim == 1:
         return _D4 / h**4
-    if ndim == 2:
-        w = np.zeros((5, 5))
-        w[2, :] += _D4
-        w[:, 2] += _D4
-        w[1:4, 1:4] += 2.0 * np.outer(_D2, _D2)
+    if ndim in (2, 3):
+        lap = _laplacian_weights(ndim)
+        w = np.zeros((5,) * ndim)
+        for idx in np.ndindex(lap.shape):
+            w[tuple(slice(i, i + 3) for i in idx)] += lap[idx] * lap
         return w / h**4
-    raise ValueError(f"biharmonic weights: ndim must be 1|2, got {ndim}")
+    raise ValueError(f"biharmonic weights: ndim must be 1|2|3, got {ndim}")
 
 
 register_operator(
@@ -305,7 +307,8 @@ register_operator(
 register_operator(
     "biharmonic",
     weights=_biharmonic_weights,
-    doc="grad^4: delta^4 / the paper's 5x5 eq.-(4) stencil (units h^-4)",
+    doc="grad^4: delta^4 / the paper's 5x5 eq.-(4) stencil / its 5x5x5 "
+    "analogue (units h^-4)",
     derivative=4,
     symmetric=True,
     zero_sum=True,

@@ -1,4 +1,4 @@
-"""The 2D Cahn–Hilliard ADI solver (paper §V, "cuCahnPentADI").
+"""The Cahn–Hilliard ADI solver (paper §V, "cuCahnPentADI"), 2D and 3D.
 
 Solves  dC/dt = D grad^2 (C^3 - C - gamma grad^2 C)  on a periodic box,
 with the two-step Beam–Warming-style ADI scheme of paper eq. (2):
@@ -12,7 +12,32 @@ with the two-step Beam–Warming-style ADI scheme of paper eq. (2):
 with L = I + (2/3) D gamma dt d^4/dx^4 (pentadiagonal, factored once), and a
 standard ADI half-step pair (paper eq. 3) to bootstrap C^1 from C^0.
 
-Three interchangeable RHS paths (validated identical in tests):
+In 3D (``CHConfig.nz`` set; the Gloster thesis, arXiv 2101.06550) the same
+three-level step gains a third implicit factor, ``L_x w = rhs``,
+``L_y u = w``, ``L_z v = u``, with grad^4 the 25-tap 5x5x5 biharmonic and
+grad^2 the 7-point Laplacian.  The 3D factors carry
+
+    L = I + (3/2) beta d^4/dx^4 = I + (D gamma dt / h^4) d^4/dx^4
+
+and not eq. (2)'s ``beta = (2/3) D gamma dt / h^4``: three factors at
+beta hold too little of the explicit cross terms
+``2 beta (d_x d_y + d_y d_z + d_z d_x)``, and the linear step's growth
+factor then exceeds 1 for beta above about 0.0137 (1.30 at 0.067).  With
+the factors' coefficient at about 1.12 beta or more, no grid mode grows
+at any beta; (3/2) beta leaves room and is the bootstrap's coefficient,
+so one factored triple serves both.  The factors change by O(dt) and act
+on ``v = C^{n+1} - Cbar = O(dt^2)``, so the step stays second order, as
+eq. (2)'s own splitting error is.  On a field constant along z, ``L_z`` is
+the identity and the 3D step is the 2D step of eq. (2) with its factors'
+coefficient raised to (3/2) beta.  The bootstrap is implicit in all three
+directions and first order, as eq. (3) is:
+
+    (I + b d_x^4)(I + b d_y^4)(I + b d_z^4)(C^1 - C^0)
+        = dt D [-gamma grad^4 C^0 + grad^2 (C^3 - C)^0],   b = D gamma dt / h^4
+
+3D runs ``rhs_mode='stencil'`` only.
+
+Three interchangeable RHS paths in 2D (validated identical in tests):
 
 - ``rhs_mode='stencil'`` — paper-faithful: the RHS is assembled from cuSten
   plan calls: a 5x5 weighted XY plan for grad^4, and a 3x3 *function-pointer*
@@ -57,6 +82,7 @@ from repro.kernels import ops as _ops
 _D4 = np.asarray(_api.get_operator("biharmonic").weights(1))  # eq. (4b)
 _D2 = np.asarray(_api.get_operator("laplacian").weights(1))  # eq. (4a)
 _LAP = np.asarray(_api.get_operator("laplacian").weights(2))
+_LAP3 = np.asarray(_api.get_operator("laplacian").weights(3))
 
 
 def biharmonic_weights() -> np.ndarray:
@@ -98,6 +124,27 @@ def cube_laplacian_point_fn(windows, coeffs):
 
 @dataclasses.dataclass(frozen=True)
 class CHConfig:
+    """One Cahn–Hilliard run: grid, box, time step, physics and execution.
+
+    ``nz=None`` is the paper's 2D scheme on ``(ny, nx)``; an integer ``nz``
+    makes it the 3D scheme on ``(nz, ny, nx)`` (``rhs_mode='stencil'``),
+    whose z side is ``nz * dx``: the grid is uniform.
+
+    The default ``dt = 1e-3`` suits small grids only.  In 2D, with the
+    deep-quench initial field, it overflows within two steps at 1024^2 and
+    above, in float64 as in float32: the bootstrap (eq. 3) treats one
+    direction's hyperdiffusion explicitly and multiplies grid-scale noise
+    by about ``8 D gamma dt / h^4``.  The 3D bootstrap and step hold every
+    direction's hyperdiffusion implicitly, and no grid mode of their
+    linear part grows at any dt (module doc); at dt = 1e-3 the 3D scheme
+    has been run at 64^3 (``beta = (2/3) D gamma dt / h^4`` about 0.043) and
+    not at 1024 a side.  Grids of 1024 or more a side take the rule the
+    benchmark's configurations use instead,
+    ``dt = dt_factor * h^4 / (D gamma)``, which holds
+    ``beta = (2/3) dt_factor`` fixed on every grid: ``dt_factor = 0.1``,
+    beta = 0.067, in 2D and in 3D.
+    """
+
     nx: int = 1024
     ny: int = 1024
     lx: float = 2.0 * np.pi
@@ -114,6 +161,8 @@ class CHConfig:
     # Create-time autotuning ('off' | 'cached' | 'force'): measure solve /
     # stream configurations once at Create, remember them on disk
     tune: str = "off"
+    # the third axis: None keeps the paper's 2D scheme
+    nz: int | None = None
 
     @property
     def dx(self) -> float:
@@ -123,9 +172,20 @@ class CHConfig:
     def dy(self) -> float:
         return self.ly / self.ny
 
+    @property
+    def shape(self) -> tuple[int, ...]:
+        """``(ny, nx)``, or ``(nz, ny, nx)`` in 3D."""
+        return (self.ny, self.nx) if self.nz is None else (self.nz, self.ny, self.nx)
+
     def validate(self):
         if abs(self.dx - self.dy) > 1e-12:
             raise ValueError("paper scheme assumes a uniform grid dx == dy")
+        if self.nz is not None and self.rhs_mode != "stencil":
+            raise ValueError(
+                f"rhs_mode={self.rhs_mode!r} is 2D only: a 3D configuration "
+                "(nz set) assembles its RHS from Stencil3D plans, "
+                "rhs_mode='stencil'"
+            )
         from repro.tune import check_mode
 
         check_mode(self.tune)
@@ -149,13 +209,17 @@ class CahnHilliardADI:
         # entry — the key is (shape, dtype, backend), not the alpha value,
         # because substitution cost does not depend on the coefficients.
         beta_full = (2.0 / 3.0) * cfg.D * cfg.gamma * cfg.dt / h4
-        beta_half = 0.5 * cfg.D * cfg.gamma * cfg.dt / h4
         mk_op = functools.partial(
-            _api.create, "hyperdiffusion", (cfg.ny, cfg.nx), mode="adi",
+            _api.create, "hyperdiffusion", cfg.shape, mode="adi",
             cyclic=True, dtype=dtype, backend=cfg.backend,
             streams=cfg.streams, max_tile_bytes=cfg.max_tile_bytes,
         )
+        self._evolve_cache = {}  # chunk length -> compiled donated driver
+        if cfg.nz is not None:
+            self._create_3d(mk_op, dtype)
+            return
         self.op_full = mk_op(alpha=beta_full, tune=cfg.tune)
+        beta_half = 0.5 * cfg.D * cfg.gamma * cfg.dt / h4
         self.op_half = mk_op(
             alpha=beta_half,
             tune="cached" if cfg.tune == "force" else cfg.tune,
@@ -164,7 +228,6 @@ class CahnHilliardADI:
         self._unroll = (self.op_full.x_cfg or {}).get("unroll", 1)
         self._streams_eff = cfg.streams
         self._chunk_rows_eff = None  # None -> choose_chunk_rows heuristic
-        self._evolve_cache = {}  # chunk length -> compiled donated driver
 
         # Create: the stencil plans (paper-faithful RHS path), all through
         # the four-function facade — shape doubles as the tuning shape.
@@ -218,6 +281,27 @@ class CahnHilliardADI:
                 self._streams_eff, self._chunk_rows_eff = (
                     self._tune_stream_geometry(dtype)
                 )
+
+    def _create_3d(self, mk_op, dtype):
+        """The 3D Create: one ADIOperator3D triple at (3/2) beta for the
+        step and the bootstrap (module doc), and the two Stencil3D plans of
+        the RHS — the 5x5x5 biharmonic and the function-pointer Laplacian
+        of (C^3 - C)."""
+        cfg = self.cfg
+        self.op_full = mk_op(
+            alpha=cfg.D * cfg.gamma * cfg.dt * self.inv_h4, tune=cfg.tune
+        )
+        mk = functools.partial(
+            _api.create, shape=cfg.shape, mode="xyz", bc="periodic",
+            dtype=dtype, backend=cfg.backend, streams=cfg.streams,
+            max_tile_bytes=cfg.max_tile_bytes, tune=cfg.tune,
+        )
+        self.plan_bih = mk("biharmonic")
+        self.plan_lap_cube = mk(
+            cube_laplacian_point_fn,
+            coeffs=_LAP3.ravel(),
+            extents={k: 1 for k in ("left", "right", "top", "bottom", "front", "back")},
+        )
 
     # -- batched-1D directional assembly (rhs_mode='batch1d') ----------------
     def _cross_batch1d(self, c: jnp.ndarray) -> jnp.ndarray:
@@ -431,6 +515,8 @@ class CahnHilliardADI:
         else:
             w = self.op_full.solve_x(self.rhs(c_n, c_nm1))
         v = self.op_full.solve_y(w)
+        if self.cfg.nz is not None:
+            v = self.op_full.solve_z(v)  # the third implicit factor
         with obs.stage("ch.update"):
             c_np1 = 2.0 * c_n - c_nm1 + v
         return c_np1, c_n
@@ -439,6 +525,13 @@ class CahnHilliardADI:
     @obs.stage("ch.bootstrap")
     def initial_step(self, c0: jnp.ndarray) -> jnp.ndarray:
         cfg = self.cfg
+        if cfg.nz is not None:
+            # implicit in all three directions, first order (module doc)
+            rhs = cfg.dt * cfg.D * (
+                -cfg.gamma * self.inv_h4 * self.plan_bih.apply(c0)
+                + self.inv_h2 * self.plan_lap_cube.apply(c0)
+            )
+            return c0 + _api.compute(self.op_full, rhs)
         half = 0.5 * cfg.dt
         coef_h = cfg.D * cfg.gamma * self.inv_h4
 

@@ -204,3 +204,49 @@ def test_ch_evolve_kernels_carry_their_sweep_stage(one_chip, tpu_dispatch):
     stages = [re.search(r'op_name="[^"]*?custen\.(adi\.[xy])/', k) for k in kernels]
     assert kernels and all(stages), kernels
     assert {s.group(1) for s in stages} == {"adi.x", "adi.y"}
+
+
+def _outermost_stages(text):
+    """The outermost ``custen.`` stage of each Pallas kernel's ``op_name``."""
+    import re
+
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels, "dispatch did not pick the Pallas kernels"
+    return [re.search(r'op_name="[^"]*?custen\.([a-z0-9_.]+?)/', k) for k in kernels]
+
+
+def test_ch3d_evolve_kernels_carry_their_stage(one_chip, tpu_dispatch):
+    # the 3D CH hot loop at the benchmark's 512^3: both RHS stencil plans
+    # charged to ch.rhs, the row, plane and column penta sweeps to their axis
+    from repro.core.cahn_hilliard import CahnHilliardADI, CHConfig
+
+    n = 512
+    h = 2 * np.pi / n
+    solver = CahnHilliardADI(CHConfig(
+        nx=n, ny=n, nz=n, dt=0.1 * h**4 / (0.6 * 0.01), dtype="float32",
+        rhs_mode="stencil", backend="auto"))
+    c = jax.ShapeDtypeStruct((n, n, n), F32, sharding=one_chip)
+    stages = _outermost_stages(solver.make_evolve(4).lower(c, c).compile().as_text())
+    assert all(stages)
+    assert sorted(s.group(1) for s in stages) == [
+        "adi.x", "adi.y", "adi.z", "ch.rhs", "ch.rhs"]
+
+
+def test_heat_lod_scan_kernels_carry_their_stage(one_chip, tpu_dispatch):
+    # backward-Euler LOD heat at 256^3: c <- S_z S_y S_x c, 16 steps a scan
+    import repro
+
+    n = 256
+    op = repro.create("diffusion", (n, n, n), mode="adi", alpha=1.0,
+                      dtype=F32, backend="auto")
+
+    def advance(c):
+        step = lambda c, _: (repro.compute(op, c), None)  # noqa: E731
+        return jax.lax.scan(step, c, None, length=16)[0]
+
+    c = jax.ShapeDtypeStruct((n, n, n), F32, sharding=one_chip)
+    text = jax.jit(advance, donate_argnums=0).lower(c).compile().as_text()
+    stages = _outermost_stages(text)
+    assert all(stages)
+    assert sorted(s.group(1) for s in stages) == ["adi.x", "adi.y", "adi.z"]
