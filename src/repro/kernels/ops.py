@@ -22,6 +22,7 @@ from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import ref as _ref
 from repro.kernels.stencil1d_batch import stencil1d_batch_pallas
@@ -432,8 +433,24 @@ def _interior_mask_3d(shape, halos):
     )
 
 
+def _nonzero_taps(coeffs, point_fn) -> tuple[int, ...] | None:
+    """The ascending flat indices of the non-zero weights, when the weights
+    are known while the program is traced (a plan closed over, not passed
+    into ``jit``) and some are zero; else ``None``, every window.  A
+    function-mode ``point_fn`` is handed every window by position."""
+    if point_fn is not _ref.weighted_point_fn or isinstance(
+        coeffs, jax.core.Tracer
+    ):
+        return None
+    taps = np.flatnonzero(np.asarray(coeffs))
+    if taps.size in (0, np.size(coeffs)):
+        return None
+    return tuple(int(k) for k in taps)
+
+
 def _stencil3d_pallas_padded(
-    data, coeffs, out_init, *, point_fn, halos, bc, tz, ty, pz, py, interpret,
+    data, coeffs, out_init, *, point_fn, halos, bc, tz, ty, pz, py, taps,
+    interpret,
 ):
     """Pallas dispatch for awkward 3D extents (prime/odd ``nz``/``ny``).
 
@@ -461,6 +478,7 @@ def _stencil3d_pallas_padded(
         bc="np",
         tz=tz,
         ty=ty,
+        taps=taps,
         interpret=interpret,
     )
     out = jax.lax.slice(out, (fr, tp, lf), (fr + nz, tp + ny, lf + nx))
@@ -515,6 +533,7 @@ def stencil_apply_3d(
         )
     if backend == "pallas":
         _pallas_dispatch("stencil3d")
+        taps = _nonzero_taps(coeffs, point_fn)
         if not clean:
             if tile is not None:
                 raise ValueError(
@@ -536,7 +555,7 @@ def stencil_apply_3d(
             return _stencil3d_pallas_padded(
                 data, coeffs, out_init,
                 point_fn=point_fn, halos=halos, bc=bc,
-                tz=ptz, ty=pty, pz=pz, py=py,
+                tz=ptz, ty=pty, pz=pz, py=py, taps=taps,
                 interpret=_should_interpret(interpret),
             )
         return stencil3d_pallas(
@@ -548,6 +567,7 @@ def stencil_apply_3d(
             bc=bc,
             tz=tz,
             ty=ty,
+            taps=taps,
             interpret=_should_interpret(interpret),
         )
     if backend == "jnp":

@@ -8,7 +8,8 @@ carries the **full x row**, so x-halos are in-VMEM rolls.  VMEM budget:
 9 × 256 KiB ≈ 2.3 MiB.
 
 Supports arbitrary box stencils (fr/bk, tp/bt, lf/rt halos), weighted or
-function mode, periodic / np boundaries.  Oracle:
+function mode, periodic / np boundaries; in weighted mode a static tap
+set (``taps``) builds only the windows whose weight is not zero.  Oracle:
 :func:`repro.kernels.ref.stencil3d_ref`.
 """
 
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.kernels.ref import weighted_point_fn
 from repro.util import block_spec, clamp_block, wrap_block
 
@@ -35,6 +37,7 @@ def _kernel(
     shape,
     tz: int,
     ty: int,
+    taps,
 ):
     fr, bk, tp, bt, lf, rt = halos
     nz, ny, nx = shape
@@ -69,20 +72,28 @@ def _kernel(
         bb = zband(1)[:, :hy, :]
         band = jnp.concatenate([tb, band, bb], axis=1)
 
+    sy, sx = tp + bt + 1, lf + rt + 1
+    keep = range((fr + bk + 1) * sy * sx) if taps is None else taps
+    subs = {}
     windows = []
-    for c in range(fr + bk + 1):
-        z0 = hz - fr + c
-        for a in range(tp + bt + 1):
-            y0 = hy - tp + a
-            sub = jax.lax.slice(
+    for k in keep:
+        c, rem = divmod(k, sy * sx)
+        a, b = divmod(rem, sx)
+        if (c, a) not in subs:
+            z0, y0 = hz - fr + c, hy - tp + a
+            subs[(c, a)] = jax.lax.slice(
                 band, (z0, y0, 0), (z0 + tz, y0 + ty, nx)
             )
-            for b in range(lf + rt + 1):
-                # x-halo via in-VMEM roll on the full row (a zero shift
-                # is no roll: Mosaic refuses the empty slice it makes)
-                shift = lf - b
-                windows.append(jnp.roll(sub, shift, axis=2) if shift else sub)
-    val = point_fn(windows, coeffs)
+        # x-halo via in-VMEM roll on the full row (a zero shift is no
+        # roll: Mosaic refuses the empty slice it makes)
+        shift = lf - b
+        sub = subs[(c, a)]
+        windows.append(jnp.roll(sub, shift, axis=2) if shift else sub)
+    if taps is None:
+        val = point_fn(windows, coeffs)
+    else:
+        # the all-taps sum in the same order, less its exact-zero products
+        val = weighted_point_fn(windows, [coeffs[k] for k in taps])
 
     if bc == "np":
         zi = pl.program_id(0)
@@ -101,7 +112,9 @@ def _kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("point_fn", "halos", "bc", "tz", "ty", "interpret"),
+    static_argnames=(
+        "point_fn", "halos", "bc", "tz", "ty", "taps", "interpret",
+    ),
 )
 def stencil3d_pallas(
     data: jnp.ndarray,
@@ -113,8 +126,16 @@ def stencil3d_pallas(
     bc: str = "periodic",
     tz: int = 4,
     ty: int = 8,
+    taps: tuple[int, ...] | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """Apply a 3D box stencil on ``(nz, ny, nx)`` by ``(tz, ty)`` blocks.
+
+    ``taps`` (weighted mode only): the ascending flat indices of the
+    weights that are not zero.  The kernel then builds, rolls and sums
+    those windows alone; ``None`` builds every window of the box.  The
+    weights stay a runtime operand either way.
+    """
     nz, ny, nx = data.shape
     fr, bk, tp, bt, lf, rt = halos
     hz, hy = max(fr, bk), max(tp, bt)
@@ -123,6 +144,12 @@ def stencil3d_pallas(
     if hz > tz or hy > ty or max(lf, rt) > nx:
         raise ValueError("halo exceeds tile")
     gz, gy = nz // tz, ny // ty
+    if taps is not None:
+        if point_fn is not weighted_point_fn:
+            raise ValueError("taps needs the weighted point_fn")
+        obs.add("stencil3d.sparse_applies", 1)
+        n_win = (fr + bk + 1) * (tp + bt + 1) * (lf + rt + 1)
+        obs.add("stencil3d.taps_skipped", n_win - len(taps))
 
     move = wrap_block if bc == "periodic" else clamp_block
 
@@ -150,7 +177,7 @@ def stencil3d_pallas(
     return pl.pallas_call(
         functools.partial(
             _kernel, point_fn=point_fn, halos=halos, hz=hz, hy=hy,
-            bc=bc, shape=(nz, ny, nx), tz=tz, ty=ty,
+            bc=bc, shape=(nz, ny, nx), tz=tz, ty=ty, taps=taps,
         ),
         grid=(gz, gy),
         in_specs=in_specs,

@@ -21,8 +21,13 @@ holds the library's names beside the device ops, on one clock:
 - :func:`counters` — monotone counts of the programs JAX compiled or
   fetched from its persistent cache, fed by :mod:`jax.monitoring`
   listeners registered once on import; the listeners run on a compile,
-  never on a step.  Take the difference of two readings around the part
-  of a run you want to count.
+  never on a step.  Beside them, two counts the 3D stencil kernel adds
+  when it is traced: ``stencil3d.sparse_applies``, the applies traced
+  with a static tap set (weights known when the program was traced, some
+  of them zero), and ``stencil3d.taps_skipped``, the zero taps those
+  applies dropped (100 for the 5x5x5 biharmonic, 20 for the 7-point
+  Laplacian).  Take the difference of two readings around the part of a
+  run you want to count.
 """
 
 from __future__ import annotations
@@ -45,7 +50,10 @@ _CACHE_EVENTS = {
 }
 
 _lock = threading.Lock()
-_counts = {"programs": 0, "compile_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+_counts = {
+    "programs": 0, "compile_s": 0.0, "cache_hits": 0, "cache_misses": 0,
+    "stencil3d.sparse_applies": 0, "stencil3d.taps_skipped": 0,
+}
 
 
 def stage(name: str):
@@ -60,6 +68,13 @@ def span(name: str):
     context manager or decorator around host code."""
     with jax.profiler.TraceAnnotation(PREFIX + name):
         yield
+
+
+def add(name: str, n: int) -> None:
+    """Add ``n`` to the library's own count ``name`` (a trace-time count:
+    callers run it while JAX traces, never on a step)."""
+    with _lock:
+        _counts[name] += n
 
 
 def _on_duration(event: str, seconds: float, **_) -> None:
@@ -83,8 +98,10 @@ jax.monitoring.register_event_listener(_on_event)
 def counters() -> dict:
     """The counts since import: ``programs`` (executables obtained),
     ``compile_s`` (their seconds), ``cache_hits``/``cache_misses`` (the
-    persistent compile cache), and the Create-time tuner's counts as
-    ``tune.*`` (read from :data:`repro.tune.stats`)."""
+    persistent compile cache), ``stencil3d.sparse_applies``/
+    ``stencil3d.taps_skipped`` (3D stencil applies traced with a tap set,
+    and the zero taps they dropped), and the Create-time tuner's counts
+    as ``tune.*`` (read from :data:`repro.tune.stats`)."""
     from repro.tune import stats
 
     with _lock:

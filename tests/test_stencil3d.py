@@ -3,6 +3,7 @@ kernel vs the oracle, the dispatcher's alignment-padded path on prime/odd
 extents, the :class:`Stencil3D` plan API on the dimension-agnostic core,
 and z-slab streamed execution."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,6 +12,8 @@ try:
 except ImportError:  # optional dep: deterministic sweep fallback
     from _hypothesis_fallback import given, settings, strategies as st
 
+import repro
+from repro import obs
 from repro.core.stencil import (
     PlanCore,
     Stencil3D,
@@ -144,6 +147,129 @@ class TestDispatcher3D:
             data, w, halos=(1, 1, 1, 1, 1, 1), bc="periodic", backend="auto"
         )
         np.testing.assert_allclose(out, jnp.zeros_like(data), atol=1e-12)
+
+
+def _single_tap_weights():
+    w = np.zeros((3, 3, 3))
+    w[0, 2, 1] = 0.75
+    return w
+
+
+# (weights box, halo, zero taps dropped)
+TAP_SETS = {
+    "biharmonic": (repro.get_operator("biharmonic").weights(3), 2, 100),
+    "laplacian7": (laplacian3d_weights(), 1, 20),
+    "single": (_single_tap_weights(), 1, 26),
+}
+
+
+def _sparse_counts():
+    c = obs.counters()
+    return c["stencil3d.sparse_applies"], c["stencil3d.taps_skipped"]
+
+
+class TestTapSet3D:
+    """Weighted applies whose weights are known while the program is
+    traced build only their non-zero taps: the same bits as every window,
+    and function-mode or traced weights keep every window."""
+
+    @pytest.mark.parametrize("bc", ["periodic", "np"])
+    @pytest.mark.parametrize("name", sorted(TAP_SETS))
+    def test_tap_set_bit_matches_all_taps(self, name, bc):
+        box, h, _ = TAP_SETS[name]
+        halos = (h,) * 6
+        rng = np.random.default_rng(13)
+        data = jnp.asarray(rng.standard_normal((16, 16, 128)), jnp.float32)
+        w = jnp.asarray(np.asarray(box).ravel(), jnp.float32)
+        init = (
+            jnp.asarray(rng.standard_normal(data.shape), jnp.float32)
+            if bc == "np"
+            else None
+        )
+        sparse = ops.stencil_apply_3d(
+            data, w, init, halos=halos, bc=bc, tile=(8, 8),
+            backend="pallas", interpret=True,
+        )
+        every = stencil3d_pallas(
+            data, w, init, halos=halos, bc=bc, tz=8, ty=8, taps=None,
+            interpret=True,
+        )
+        np.testing.assert_array_equal(sparse, every)
+        ref = stencil3d_ref(data, bc=bc, halos=halos, coeffs=w, out_init=init)
+        np.testing.assert_allclose(
+            sparse, ref, **tolerance_for(jnp.float32, scale=10)
+        )
+
+    @pytest.mark.parametrize("name", sorted(TAP_SETS))
+    def test_counts_dropped_taps(self, name):
+        box, h, skipped = TAP_SETS[name]
+        jax.clear_caches()  # count a fresh trace of the kernel
+        before = _sparse_counts()
+        ops.stencil_apply_3d(
+            jnp.ones((16, 16, 128), jnp.float32),
+            jnp.asarray(np.asarray(box).ravel(), jnp.float32),
+            halos=(h,) * 6, bc="periodic", tile=(8, 8), backend="pallas",
+            interpret=True,
+        )
+        after = _sparse_counts()
+        assert (after[0] - before[0], after[1] - before[1]) == (1, skipped)
+
+    def test_function_mode_keeps_every_window(self):
+        rng = np.random.default_rng(14)
+        data = jnp.asarray(rng.standard_normal((8, 8, 128)), jnp.float32)
+
+        def fn(windows, coe):
+            return sum(c * w * w for c, w in zip(coe, windows, strict=True))
+
+        # zero coefficients a weighted apply would drop: fn still gets all
+        coe = jnp.asarray(laplacian3d_weights().ravel(), jnp.float32)
+        jax.clear_caches()
+        before = _sparse_counts()
+        plan = repro.create(
+            fn, data.shape, mode="xyz", bc="periodic", coeffs=coe,
+            extents=dict.fromkeys(
+                ("left", "right", "top", "bottom", "front", "back"), 1
+            ),
+            backend="pallas", interpret=True,
+        )
+        out = plan.apply(data)
+        assert _sparse_counts() == before
+        ref = stencil3d_ref(
+            data, bc="periodic", halos=(1,) * 6, point_fn=fn, coeffs=coe
+        )
+        np.testing.assert_allclose(
+            out, ref, **tolerance_for(jnp.float32, scale=10)
+        )
+
+    def test_traced_weights_keep_every_window(self):
+        """A plan passed into ``jit`` has traced weights: every window, and
+        new weight values, even with other zeros, reuse the trace."""
+        traces = []
+
+        @jax.jit
+        def f(p, x):
+            traces.append(1)
+            return repro.compute(p, x)
+
+        rng = np.random.default_rng(15)
+        data = jnp.asarray(rng.standard_normal((8, 8, 128)), jnp.float32)
+        mk = lambda w: repro.create(  # noqa: E731
+            w, data.shape, bc="periodic", dtype=jnp.float32,
+            backend="pallas", interpret=True,
+        )
+        jax.clear_caches()
+        before = _sparse_counts()
+        for box in (laplacian3d_weights(), _single_tap_weights()):
+            out = f(mk(box), data)
+            ref = stencil3d_ref(
+                data, bc="periodic", halos=(1,) * 6,
+                coeffs=jnp.asarray(box.ravel(), jnp.float32),
+            )
+            np.testing.assert_allclose(
+                out, ref, **tolerance_for(jnp.float32, scale=10)
+            )
+        assert len(traces) == 1, "weight-value change must not retrace"
+        assert _sparse_counts() == before
 
 
 class TestPlanAPI3D:

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+import repro
+from repro import obs
 from repro.kernels import ops
 from repro.kernels.penta import (
     CyclicPentaFactors,
@@ -123,6 +125,19 @@ def test_stencil3d_7point(one_chip, tpu_dispatch):
         ),
         one_chip, _s(256, 256, 256), _s(27),
     )
+
+
+def test_stencil3d_biharmonic_taps(one_chip, tpu_dispatch):
+    # the 3D CH cell's biharmonic: concrete weights closed over, so the
+    # kernel is traced with its 25 non-zero taps of 125
+    w = jnp.asarray(repro.get_operator("biharmonic").weights(3).ravel(), F32)
+    before = obs.counters()
+    _compile(
+        lambda x: ops.stencil_apply_3d(x, w, halos=(2,) * 6, bc="periodic"),
+        one_chip, _s(512, 512, 512),
+    )
+    after = obs.counters()
+    assert after["stencil3d.taps_skipped"] - before["stencil3d.taps_skipped"] == 100
 
 
 def test_ch_rhs(one_chip, tpu_dispatch):
