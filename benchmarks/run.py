@@ -1,8 +1,7 @@
 """Benchmark harness.  One function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows.  ``derived`` carries the
-figure-of-merit for the row (points/s, coarsening exponent, roofline
-fraction, ...).
+figure-of-merit for the row (points/s, coarsening exponent, ...).
 
     PYTHONPATH=src python -m benchmarks.run            # standard set
     PYTHONPATH=src python -m benchmarks.run --full     # + Fig-1 physics run
@@ -17,7 +16,6 @@ artifact.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import sys
 import time
@@ -146,7 +144,7 @@ def bench_stream(smoke: bool = False):
     from repro.core.cahn_hilliard import biharmonic_weights
     from repro.kernels.ops import stencil_apply
     from repro.kernels.ref import stencil2d_ref
-    from repro.launch.stream import stream_stencil_apply
+    from repro.core.stream import stream_stencil_apply
 
     rows = []
     rng = np.random.default_rng(0)
@@ -717,38 +715,6 @@ def _walltime(fn):
     return time.perf_counter() - t0
 
 
-# ---------------------------------------------------------------------------
-# §Roofline — table from the dry-run artifacts
-# ---------------------------------------------------------------------------
-
-
-def bench_roofline_table(smoke: bool = False):
-    paths = sorted(
-        glob.glob("artifacts/dryrun*/**/*.json", recursive=True)
-        + glob.glob("artifacts/dryrun*/*.json")
-    )
-    rows = []
-    seen = {}
-    for path in paths:
-        with open(path) as f:
-            for rec in json.load(f):
-                if rec.get("status") != "ok":
-                    continue
-                key = (rec["arch"], rec["shape"], rec["mesh"])
-                seen[key] = rec  # latest wins
-    for (arch, shape, mesh), rec in sorted(seen.items()):
-        r = rec["roofline"]
-        bound = max(r["t_compute"], r["t_memory"], r["t_collective"])
-        rows.append(
-            (
-                f"roofline_{arch}_{shape}_{mesh}",
-                bound * 1e6,
-                f"dom={r['dominant']};frac={r['roofline_frac']}",
-            )
-        )
-    return rows
-
-
 def bench_audit(smoke: bool = False):
     """Wall time of the static-analysis gate itself: one invariant +
     cost audit over a one-cell slice (what a pre-commit hook would pay),
@@ -813,7 +779,6 @@ BENCHMARKS = [
     ("serve", bench_serve, False, ("serve_",)),
     ("serve_chaos", bench_serve_chaos, False, ("serve_chaos_",)),
     ("coarsening_fig1", bench_coarsening_fig1, True, ("fig1_",)),  # --full
-    ("roofline_table", bench_roofline_table, False, ("roofline_",)),
     ("audit", bench_audit, False, ("audit_",)),
 ]
 

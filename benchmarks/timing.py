@@ -1,5 +1,5 @@
 """Wall-clock micro-timing helpers (CPU host; TPU numbers come from the
-dry-run roofline, not from here)."""
+chip benchmark, ``benchmarks/chip/run.py``, not from here)."""
 
 from __future__ import annotations
 

@@ -25,22 +25,25 @@ pre-facade per-dimension functions (``stencil_create_2d`` & co,
 ``make_adi_operator*``) remain importable as deprecation shims for one
 release.
 
-The package is organised as a production framework:
+The package is organised in layers, from the entry point down to the
+kernels (which import none of the layers above them):
 
 - :mod:`repro.api`        — the four-function facade + operator registry.
 - :mod:`repro.core`       — the paper's contribution: plan-based stencil
-  engine, ADI time stepping, Cahn–Hilliard / WENO applications, distributed
-  domain decomposition with halo exchange.
+  engine, ADI time stepping, Cahn–Hilliard / WENO applications, the
+  streamed tiled executor (:mod:`repro.core.stream`), distributed domain
+  decomposition with halo exchange.
 - :mod:`repro.kernels`    — Pallas TPU kernels (BlockSpec VMEM tiling) with
-  jnp oracles, for the compute hot spots the paper optimises.
-- :mod:`repro.models`     — LM substrate for the assigned architecture pool.
-- :mod:`repro.configs`    — architecture / problem configurations.
-- :mod:`repro.optim`, :mod:`repro.data`, :mod:`repro.checkpoint`,
-  :mod:`repro.runtime`    — training substrate (optimizers, pipelines,
-  fault-tolerant checkpointing, sharding rules).
-- :mod:`repro.launch`     — meshes, dry-run driver, train/serve entry points.
-- :mod:`repro.obs`        — stage scopes, host spans and compile counters
-  (``custen.*`` names in a profile; :func:`repro.obs.counters`).
+  jnp oracles and backend dispatch, for the compute hot spots the paper
+  optimises.
+- :mod:`repro.util`       — tile pickers, block-spec helpers, compile cache.
+
+Beside them: :mod:`repro.serve` (batched serving engine, ``python -m
+repro.serve``), :mod:`repro.tune` (autotuner), :mod:`repro.analysis`
+(plan auditor and HLO cost model), :mod:`repro.runtime` and
+:mod:`repro.checkpoint` (fault injection, supervised checkpointed runs),
+:mod:`repro.obs` (stage scopes, host spans and compile counters:
+``custen.*`` names in a profile; :func:`repro.obs.counters`).
 """
 
 __version__ = "2.0.0"  # tracks cuSten's published version

@@ -38,9 +38,7 @@ execution-count weighting:
 
 The parser is validated against ``cost_analysis`` on loop-free programs
 and against hand-counted FLOPs on scanned programs (tests/test_cost.py,
-tests/test_hlo_costs.py).  It lived in ``repro.launch.hlo_costs``
-(which remains as a re-export shim) before the cost auditor moved it
-here.
+tests/test_hlo_costs.py).
 
 Doctest — the parser on a really-compiled program:
 
@@ -653,6 +651,43 @@ def memory_stats(compiled) -> dict:
     return mem
 
 
+# e.g.  {0}: (0, {}, may-alias)  inside the module's input_output_alias={...}
+_ALIAS_PAIR_RE = re.compile(
+    r"\{([0-9,\s]*)\}:\s*\((\d+),\s*\{[0-9,\s]*\},\s*(may-alias|must-alias)\)"
+)
+
+
+def input_output_aliases(hlo_text: str):
+    """Parse the module header's ``input_output_alias={...}`` table.
+
+    Returns a list of ``(output_index, parameter_number, kind)`` tuples —
+    ``output_index`` is the (possibly empty) tuple index of the aliased
+    output, ``kind`` is ``'may-alias'`` or ``'must-alias'``.  An empty list
+    means the compiled module carries no donation-induced aliasing (the
+    ``donation_applied`` audit rule's failure condition)."""
+    start = hlo_text.find("input_output_alias={")
+    if start < 0:
+        return []
+    i = hlo_text.index("{", start)
+    depth, j = 0, i
+    for j in range(i, len(hlo_text)):
+        if hlo_text[j] == "{":
+            depth += 1
+        elif hlo_text[j] == "}":
+            depth -= 1
+            if depth == 0:
+                break
+    body = hlo_text[i : j + 1]
+    return [
+        (
+            tuple(int(p) for p in out_idx.replace(",", " ").split()),
+            int(param),
+            kind,
+        )
+        for out_idx, param, kind in _ALIAS_PAIR_RE.findall(body)
+    ]
+
+
 def measure_compiled(compiled) -> CostVector:
     """The execution-count-weighted cost vector of a compiled executable."""
     h = analyze_hlo(compiled.as_text())
@@ -776,6 +811,7 @@ __all__ = [
     "expected_fft",
     "expected_penta",
     "expected_stencil",
+    "input_output_aliases",
     "measure_compiled",
     "memory_stats",
     "op_bytes",

@@ -65,6 +65,7 @@ __all__ = [
     "rule",
 ]
 
+from repro.analysis.cost import input_output_aliases, parse_module
 from repro.analysis.findings import ERROR, Finding
 
 # ---------------------------------------------------------------------------
@@ -308,7 +309,7 @@ def _no_host_callback(closed_jaxpr, ctx) -> list[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# HLO rules (parsers shared with repro.launch.hlo_analysis / hlo_costs)
+# HLO rules (parsers from repro.analysis.cost)
 # ---------------------------------------------------------------------------
 
 
@@ -318,15 +319,11 @@ def _no_host_callback(closed_jaxpr, ctx) -> list[Finding]:
     "requested buffer donation must materialise as input/output aliasing",
 )
 def _donation_applied(hlo_text, ctx) -> list[Finding]:
-    from repro.launch.hlo_analysis import input_output_aliases
-
     aliases = input_output_aliases(hlo_text)
     need = int(ctx.get("min_aliased", 1))
     if len(aliases) >= need:
         return []
     try:
-        from repro.launch.hlo_costs import parse_module
-
         comps = parse_module(hlo_text)
         entry = next(iter(comps)) if comps else None
     except Exception:  # noqa: BLE001 — attribution only, never fatal

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core import stream as _stream
 from repro.core.stencil import StencilBatch1D
 from repro.kernels import spectral
 from repro.util import deprecated_shim
@@ -103,10 +104,10 @@ class ADIOperator:
     """Factored per-direction operators L = I + alpha/h^4 * delta^4.
 
     ``streams``/``max_tile_bytes`` route the batched substitutions through
-    the streamed executor (:mod:`repro.launch.stream`): the y-sweep cuts
+    the streamed executor (:mod:`repro.core.stream`): the y-sweep cuts
     its independent-systems batch into column chunks
-    (:func:`~repro.launch.stream.stream_penta_solve`), the x-sweep into
-    row chunks (:func:`~repro.launch.stream.stream_penta_solve_rows`) —
+    (:func:`~repro.core.stream.stream_penta_solve`), the x-sweep into
+    row chunks (:func:`~repro.core.stream.stream_penta_solve_rows`) —
     both transpose-free, so the implicit half of an ADI step runs on
     domains exceeding one tile.
 
@@ -141,8 +142,6 @@ class ADIOperator:
     def solve_x(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_x w = rhs along the x (last) axis of an (ny, nx) field —
         row layout, transpose-free."""
-        from repro.launch import stream as _stream
-
         backend, unroll, cfg = self._cfg(self.x_cfg)
         if backend == "fft":
             return _fft_sweep(self.sym_x, rhs, axis=-1)
@@ -174,8 +173,6 @@ class ADIOperator:
     def solve_y(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_y v = rhs along the y (first) axis of an (ny, nx) field —
         column layout, native."""
-        from repro.launch import stream as _stream
-
         backend, unroll, cfg = self._cfg(self.y_cfg)
         if backend == "fft":
             return _fft_sweep(self.sym_y, rhs, axis=0)
@@ -453,8 +450,6 @@ class ADIOperator3D:
         return cfg.get("backend", self.backend), cfg.get("unroll", 1), cfg
 
     def _should_stream(self, rhs) -> bool:
-        from repro.launch import stream as _stream
-
         return _stream.should_stream(
             rhs.shape,
             rhs.dtype.itemsize,
@@ -466,8 +461,6 @@ class ADIOperator3D:
     def solve_x(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_x w = rhs along the x (last) axis — row layout on the
         flattened (nz*ny, nx) batch, transpose-free."""
-        from repro.launch import stream as _stream
-
         backend, unroll, cfg = self._cfg(self.x_cfg)
         if backend == "fft":
             return _fft_sweep(self.sym_x, rhs, axis=-1)
@@ -499,8 +492,6 @@ class ADIOperator3D:
     def solve_y(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_y v = rhs along the y (middle) axis — plane layout,
         transpose-free."""
-        from repro.launch import stream as _stream
-
         backend, unroll, cfg = self._cfg(self.y_cfg)
         if backend == "fft":
             return _fft_sweep(self.sym_y, rhs, axis=-2)
@@ -527,8 +518,6 @@ class ADIOperator3D:
     def solve_z(self, rhs: jnp.ndarray) -> jnp.ndarray:
         """Solve L_z u = rhs along the z (first) axis — column layout on
         the (nz, ny*nx) reshape, transpose-free."""
-        from repro.launch import stream as _stream
-
         backend, unroll, cfg = self._cfg(self.z_cfg)
         if backend == "fft":
             return _fft_sweep(self.sym_z, rhs, axis=-3)

@@ -67,6 +67,7 @@ import numpy as np
 from repro import api as _api
 from repro import obs
 from repro.core import metrics as _metrics
+from repro.core import stream as _stream
 from repro.runtime import chaos as _chaos
 from repro.core.adi import (
     apply_along_x,
@@ -272,8 +273,6 @@ class CahnHilliardADI:
         # PDE, and the 2D grid subsumes choose_chunk_rows' divisor
         # heuristic (ROADMAP "tuned streaming geometry").
         if cfg.tune != "off" and cfg.rhs_mode == "fused":
-            from repro.launch import stream as _stream
-
             if _stream.should_stream(
                 (cfg.ny, cfg.nx), dtype.itemsize,
                 streams=cfg.streams, max_tile_bytes=cfg.max_tile_bytes,
@@ -328,8 +327,6 @@ class CahnHilliardADI:
     def rhs(self, c_n: jnp.ndarray, c_nm1: jnp.ndarray) -> jnp.ndarray:
         cfg = self.cfg
         if cfg.rhs_mode == "fused":
-            from repro.launch import stream as _stream
-
             if _stream.should_stream(
                 c_n.shape,
                 c_n.dtype.itemsize,
@@ -393,11 +390,10 @@ class CahnHilliardADI:
         the streamed fused sweep and return ``(streams, chunk_rows)``.
 
         ``chunk_rows=None`` in a candidate means "let
-        :func:`~repro.launch.stream.choose_chunk_rows` decide" — the
+        :func:`~repro.core.stream.choose_chunk_rows` decide" — the
         pre-grid heuristic stays in the race as one contender among the
         measured divisor heights, so tuning can only match or beat it.
         """
-        from repro.launch import stream as _stream
         from repro.tune import autotune
 
         cfg = self.cfg
@@ -467,8 +463,6 @@ class CahnHilliardADI:
         the row-layout x-sweep in its native layout, streamed when the
         domain exceeds one tile."""
         cfg = self.cfg
-        from repro.launch import stream as _stream
-
         if _stream.should_stream(
             c_n.shape,
             c_n.dtype.itemsize,

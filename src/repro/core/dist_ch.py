@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.cahn_hilliard import CahnHilliardADI, CHConfig
 from repro.core.domain import DomainDecomposition
+from repro.core.stream import stream_stencil_apply_dist
 from repro.kernels.penta import (
     cyclic_penta_solve_factored,
 )
@@ -141,8 +142,6 @@ class DistributedCahnHilliard:
         each chunk's x extent is sharded over ``dd.x_axis`` inside
         ``shard_map`` with ``ppermute`` halo exchange — the §VI.B multi-GPU
         layout fused with the §III streaming machinery."""
-        from repro.launch.stream import stream_stencil_apply_dist
-
         return stream_stencil_apply_dist(
             plan,
             field,
@@ -155,14 +154,3 @@ class DistributedCahnHilliard:
 
     def field_sharding(self) -> NamedSharding:
         return NamedSharding(self.dd.mesh, self.layouts.block)
-
-    def input_specs(self, ensemble: int | None = None):
-        """ShapeDtypeStruct stand-ins for dry-run lowering."""
-        cfg = self.cfg
-        shape = (cfg.ny, cfg.nx)
-        if ensemble:
-            shape = (ensemble,) + shape
-        sds = jax.ShapeDtypeStruct(
-            shape, jnp.dtype(cfg.dtype), sharding=self.field_sharding()
-        )
-        return sds, sds

@@ -53,7 +53,7 @@ extension on ``(nz, ny, nx)`` fields.  Halos are
 ``front/back`` (z), ``top/bottom`` (y), ``left/right`` (x); direction
 ``'x'|'y'|'z'`` takes 1D weights, ``'xyz'`` a full ``(sz, sy, sx)`` box.
 Oversized domains stream as z-slabs through
-:func:`repro.launch.stream.stream_stencil3d_apply`.
+:func:`repro.core.stream.stream_stencil3d_apply`.
 """
 
 from __future__ import annotations
@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core import stream as _stream
 from repro.kernels import ops
 from repro.kernels.ref import weighted_point_fn
 from repro.util import deprecated_shim
@@ -106,7 +107,7 @@ class PlanCore:
     and the streaming knobs (``streams`` / ``max_tile_bytes`` mirror
     cuSten's ``nStreams`` / ``numStenTop``: when set and the field exceeds
     one tile, Compute routes through the streamed tiled executor in
-    :mod:`repro.launch.stream` instead of one monolithic kernel call).
+    :mod:`repro.core.stream` instead of one monolithic kernel call).
 
     Subclasses declare their geometry (the halo fields), the tune-cache
     kernel name, and three hooks:
@@ -114,7 +115,7 @@ class PlanCore:
     - :meth:`_halo_kwargs` — the per-family halo keyword vocabulary,
       passed verbatim to both the monolithic and streamed entry points;
     - :meth:`_mono_apply` / :meth:`_stream_apply` — the kernel entry
-      points (:mod:`repro.kernels.ops` / :mod:`repro.launch.stream`);
+      points (:mod:`repro.kernels.ops` / :mod:`repro.core.stream`);
     - :meth:`_pallas_tile_grid` — the Pallas tile candidate space the
       Create-time autotuner measures on TPU.
 
@@ -228,8 +229,6 @@ class PlanCore:
 
         For ``bc='np'`` the cells within the halo of the domain edge are
         copied from ``out_init`` (zeros if not given)."""
-        from repro.launch import stream as _stream
-
         if self.backend == "fft":
             # spectral path: one symbol multiply, never streamed (the fft
             # needs the whole periodic extent; Create validated bc)
@@ -476,8 +475,6 @@ class Stencil2D(PlanCore):
         return ops.stencil_apply(*args, **kwargs)
 
     def _stream_apply(self, *args, **kwargs):
-        from repro.launch import stream as _stream
-
         return _stream.stream_stencil_apply(*args, **kwargs)
 
     def _spectral_spec(self, shape):
@@ -550,7 +547,7 @@ def _create_2d(
     indexing convention of paper §V.B.
 
     ``streams``/``max_tile_bytes`` enable the streamed tiled executor for
-    oversized domains (cuSten ``nStreams``; see :mod:`repro.launch.stream`).
+    oversized domains (cuSten ``nStreams``; see :mod:`repro.core.stream`).
     """
     if direction not in _DIRECTIONS:
         raise ValueError(f"direction must be one of {_DIRECTIONS}")
@@ -635,8 +632,6 @@ class StencilBatch1D(PlanCore):
         return ops.stencil_apply_batch1d(*args, **kwargs)
 
     def _stream_apply(self, *args, **kwargs):
-        from repro.launch import stream as _stream
-
         return _stream.stream_batch1d_apply(*args, **kwargs)
 
     def _spectral_spec(self, shape):
@@ -748,7 +743,7 @@ class Stencil3D(PlanCore):
     Halos follow the :func:`repro.kernels.ref.stencil3d_ref` convention:
     ``front/back`` along z, ``top/bottom`` along y, ``left/right`` along x.
     Oversized domains stream as z-slab chunks
-    (:func:`repro.launch.stream.stream_stencil3d_apply`).
+    (:func:`repro.core.stream.stream_stencil3d_apply`).
     """
 
     direction: str
@@ -768,8 +763,6 @@ class Stencil3D(PlanCore):
         return ops.stencil_apply_3d(*args, **kwargs)
 
     def _stream_apply(self, *args, **kwargs):
-        from repro.launch import stream as _stream
-
         return _stream.stream_stencil3d_apply(*args, **kwargs)
 
     def _pallas_tile_grid(self, shape):

@@ -41,6 +41,7 @@ from collections.abc import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.kernels.ref import weighted_point_fn
 from repro.util import ceil_div
 
@@ -116,34 +117,6 @@ def _effective_streams(streams: int | None, n_chunks: int) -> int:
 # ---------------------------------------------------------------------------
 # Halo padding + slab evaluation
 # ---------------------------------------------------------------------------
-
-
-def _pad_field(
-    data: jnp.ndarray, *, top: int, bottom: int, left: int, right: int,
-    bc: str,
-) -> jnp.ndarray:
-    """Pad the full field with its halo ring once; chunks then gather with a
-    single contiguous ``dynamic_slice``.  Periodic wraps; ``np`` pads zeros
-    (those windows are masked to ``out_init`` afterwards)."""
-    if bc == "periodic":
-        if top or bottom:
-            parts = []
-            if top:
-                parts.append(data[-top:, :])
-            parts.append(data)
-            if bottom:
-                parts.append(data[:bottom, :])
-            data = jnp.concatenate(parts, axis=0)
-        if left or right:
-            parts = []
-            if left:
-                parts.append(data[:, -left:])
-            parts.append(data)
-            if right:
-                parts.append(data[:, :right])
-            data = jnp.concatenate(parts, axis=1)
-        return data
-    return jnp.pad(data, ((top, bottom), (left, right)))
 
 
 def _slab_windows(
@@ -312,11 +285,9 @@ def stream_stencil_apply(
         out_init = jnp.zeros_like(data)
 
     if interpret is None:
-        from repro.kernels import ops
-
         interpret = not ops.on_tpu()
 
-    padded = _pad_field(
+    padded = ops.pad_field(
         data, top=top, bottom=bottom, left=left, right=right, bc=bc
     )
     out_buf = jnp.zeros_like(data)
@@ -336,28 +307,6 @@ def stream_stencil_apply(
         compute=compute,
         interpret=interpret,
     )
-
-
-def _pad_field_3d(data: jnp.ndarray, *, halos, bc: str) -> jnp.ndarray:
-    """Halo-pad an ``(nz, ny, nx)`` field on all three axes (wrap for
-    periodic, zeros for ``np``) — the 3D counterpart of
-    :func:`_pad_field`, shared by the z-slab executor and the
-    alignment-padded 3D kernel dispatch."""
-    fr, bk, tp, bt, lf, rt = halos
-    if bc == "periodic":
-        for axis, (lo, hi) in enumerate(((fr, bk), (tp, bt), (lf, rt))):
-            if lo or hi:
-                parts = []
-                if lo:
-                    parts.append(jax.lax.slice_in_dim(
-                        data, data.shape[axis] - lo, data.shape[axis], axis=axis
-                    ))
-                parts.append(data)
-                if hi:
-                    parts.append(jax.lax.slice_in_dim(data, 0, hi, axis=axis))
-                data = jnp.concatenate(parts, axis=axis)
-        return data
-    return jnp.pad(data, ((fr, bk), (tp, bt), (lf, rt)))
 
 
 def _slab_windows_3d(slab: jnp.ndarray, *, halos, rows: int, ny: int, nx: int):
@@ -384,8 +333,6 @@ def _slab_apply_pallas_3d(slab, coeffs, *, point_fn, halos, rows, ny, nx,
     exactly the full-support interior — which is exactly the chunk.
     Awkward slab extents route through the alignment-padded dispatch in
     :func:`repro.kernels.ops.stencil_apply_3d`."""
-    from repro.kernels import ops
-
     fr, bk, tp, bt, lf, rt = halos
     out = ops.stencil_apply_3d(
         slab,
@@ -520,11 +467,9 @@ def stream_stencil3d_apply(
         out_init = jnp.zeros_like(data)
 
     if interpret is None:
-        from repro.kernels import ops
-
         interpret = not ops.on_tpu()
 
-    padded = _pad_field_3d(data, halos=halos, bc=bc)
+    padded = ops.pad_field_3d(data, halos=halos, bc=bc)
     out_buf = jnp.zeros_like(data)
     return _stream_exec_3d(
         padded,
@@ -920,7 +865,7 @@ def stream_ch_rhs(
     n_chunks = ny // rows
 
     pad = functools.partial(
-        _pad_field, top=h, bottom=h, left=h, right=h, bc="periodic"
+        ops.pad_field, top=h, bottom=h, left=h, right=h, bc="periodic"
     )
     return _ch_rhs_stream_exec(
         pad(c_n),
@@ -1019,7 +964,7 @@ def stream_ch_rhs_xsweep(
     n_chunks = ny // rows
 
     pad = functools.partial(
-        _pad_field, top=h, bottom=h, left=h, right=h, bc="periodic"
+        ops.pad_field, top=h, bottom=h, left=h, right=h, bc="periodic"
     )
     return _ch_xsweep_stream_exec(
         pad(c_n),
@@ -1139,7 +1084,7 @@ def stream_stencil_apply_dist(
         out_init = jnp.zeros_like(field)
 
     # y halos gathered host-side into each slab; x halos exchanged on-mesh.
-    padded = _pad_field(field, top=top, bottom=bottom, left=0, right=0, bc=bc)
+    padded = ops.pad_field(field, top=top, bottom=bottom, left=0, right=0, bc=bc)
 
     def local(slab_loc, init_loc, start):
         lf, rt = _exchange_1d(slab_loc, left, right, 1, dd.x_axis, n_x)
@@ -1203,8 +1148,6 @@ def resolve_compute(backend: str) -> str:
     if backend == "pallas":
         return "pallas"
     if backend == "auto":
-        from repro.kernels import ops
-
         return "pallas" if ops.on_tpu() else "jnp"
     return "jnp"
 

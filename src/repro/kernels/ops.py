@@ -90,14 +90,33 @@ def _lane_aligned(t: int, n: int) -> bool:
     return t % 128 == 0 or t == n
 
 
-def _halo_pad_2d(data, *, top, bottom, left, right, bc):
-    """Halo-pad a field (wrap for periodic, zeros for np) — the streamed
-    executor's padding, reused for alignment-padded kernel dispatch."""
-    from repro.launch.stream import _pad_field
-
-    return _pad_field(
-        data, top=top, bottom=bottom, left=left, right=right, bc=bc
-    )
+def pad_field(
+    data: jnp.ndarray, *, top: int, bottom: int, left: int, right: int,
+    bc: str,
+) -> jnp.ndarray:
+    """Pad the full field with its halo ring once; chunks then gather with a
+    single contiguous ``dynamic_slice``.  Periodic wraps; ``np`` pads zeros
+    (those windows are masked to ``out_init`` afterwards).  Shared by the
+    streamed executor and the alignment-padded kernel dispatch."""
+    if bc == "periodic":
+        if top or bottom:
+            parts = []
+            if top:
+                parts.append(data[-top:, :])
+            parts.append(data)
+            if bottom:
+                parts.append(data[:bottom, :])
+            data = jnp.concatenate(parts, axis=0)
+        if left or right:
+            parts = []
+            if left:
+                parts.append(data[:, -left:])
+            parts.append(data)
+            if right:
+                parts.append(data[:, :right])
+            data = jnp.concatenate(parts, axis=1)
+        return data
+    return jnp.pad(data, ((top, bottom), (left, right)))
 
 
 def _stencil2d_pallas_padded(
@@ -115,7 +134,7 @@ def _stencil2d_pallas_padded(
     output ever reads them.
     """
     ny, nx = data.shape
-    padded = _halo_pad_2d(
+    padded = pad_field(
         data, top=top, bottom=bottom, left=left, right=right, bc=bc
     )
     sy, sx = padded.shape
@@ -290,7 +309,7 @@ def _stencil1d_pallas_padded(
     original stack back out.  Padded rows are junk rows that rows of the
     real stack never read (rows are independent)."""
     B, M = data.shape
-    padded = _halo_pad_2d(data, top=0, bottom=0, left=left, right=right, bc=bc)
+    padded = pad_field(data, top=0, bottom=0, left=left, right=right, bc=bc)
     sb, sm = padded.shape
     padded = jnp.pad(padded, ((0, pb - sb), (0, pm - sm)))
     out = stencil1d_batch_pallas(
@@ -448,6 +467,28 @@ def _nonzero_taps(coeffs, point_fn) -> tuple[int, ...] | None:
     return tuple(int(k) for k in taps)
 
 
+def pad_field_3d(data: jnp.ndarray, *, halos, bc: str) -> jnp.ndarray:
+    """Halo-pad an ``(nz, ny, nx)`` field on all three axes (wrap for
+    periodic, zeros for ``np``) — the 3D counterpart of
+    :func:`pad_field`, shared by the z-slab executor and the
+    alignment-padded 3D kernel dispatch."""
+    fr, bk, tp, bt, lf, rt = halos
+    if bc == "periodic":
+        for axis, (lo, hi) in enumerate(((fr, bk), (tp, bt), (lf, rt))):
+            if lo or hi:
+                parts = []
+                if lo:
+                    parts.append(jax.lax.slice_in_dim(
+                        data, data.shape[axis] - lo, data.shape[axis], axis=axis
+                    ))
+                parts.append(data)
+                if hi:
+                    parts.append(jax.lax.slice_in_dim(data, 0, hi, axis=axis))
+                data = jnp.concatenate(parts, axis=axis)
+        return data
+    return jnp.pad(data, ((fr, bk), (tp, bt), (lf, rt)))
+
+
 def _stencil3d_pallas_padded(
     data, coeffs, out_init, *, point_fn, halos, bc, tz, ty, pz, py, taps,
     interpret,
@@ -462,11 +503,9 @@ def _stencil3d_pallas_padded(
     result back out.  The alignment zeros sit strictly beyond the halo
     ring, so no valid output ever reads them.
     """
-    from repro.launch.stream import _pad_field_3d
-
     nz, ny, nx = data.shape
     fr, bk, tp, bt, lf, rt = halos
-    padded = _pad_field_3d(data, halos=halos, bc=bc)
+    padded = pad_field_3d(data, halos=halos, bc=bc)
     sz, sy, sx = padded.shape
     padded = jnp.pad(padded, ((0, pz - sz), (0, py - sy), (0, 0)))
     out = stencil3d_pallas(

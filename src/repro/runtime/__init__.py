@@ -1,6 +1,5 @@
-"""Distributed runtime: sharding rules, fault tolerance, elasticity,
-and the chaos-hardening layer (deterministic fault injection +
-self-healing long-run driving).
+"""Runtime: fault tolerance and the chaos-hardening layer (deterministic
+fault injection + self-healing long-run driving).
 
 Light-on-import by design: :mod:`repro.runtime.chaos` and
 :mod:`repro.runtime.fault` are stdlib-only (they are imported by leaf
@@ -23,7 +22,6 @@ from repro.runtime.chaos import (
 from repro.runtime.fault import (
     Heartbeat,
     HeartbeatStatus,
-    StragglerMonitor,
     SupervisorReport,
     read_heartbeat,
     supervise,
@@ -38,7 +36,6 @@ __all__ = [
     "InjectedCrash",
     "InjectedFault",
     "InjectedIOError",
-    "StragglerMonitor",
     "SupervisorReport",
     "TransientError",
     "WorkerDeath",

@@ -1,66 +1,18 @@
-"""Fault tolerance: failure supervision, straggler detection, heartbeats.
+"""Fault tolerance: failure supervision and heartbeats.
 
-At 1000+ nodes the dominant events are (a) hardware failures — handled by
-checkpoint/restart through the supervisor loop, (b) stragglers — detected by
-the step-time monitor, (c) hangs — detected externally via the heartbeat
-file.  All three are deliberately simple, deterministic mechanisms that
-compose with the step-keyed data pipeline for bit-exact resume.
+Two events matter over a long run: (a) failures — handled by
+checkpoint/restart through the supervisor loop, (b) hangs — detected
+externally via the heartbeat file.  Both are deliberately simple,
+deterministic mechanisms; :mod:`repro.runtime.resilient` composes them
+with the checkpointer for bit-exact resume of a Cahn–Hilliard run.
 """
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import os
 import time
 from collections.abc import Callable
-
-
-class StragglerMonitor:
-    """EWMA step-time monitor.  On TPU pods the slowest participant sets the
-    step time, so a persistent multiplier over the EWMA indicates a
-    straggling host/chip; the policy hook decides (log, re-shard, evict).
-
-    ``max_events`` bounds the retained event records — a week-long run on
-    a flaky host must not grow an unbounded list; the newest events win
-    (``on_straggler`` still sees every flagged step as it happens)."""
-
-    def __init__(
-        self,
-        *,
-        alpha: float = 0.1,
-        threshold: float = 2.0,
-        warmup_steps: int = 5,
-        max_events: int = 256,
-        on_straggler: Callable[[int, float, float], None] | None = None,
-    ):
-        self.alpha = alpha
-        self.threshold = threshold
-        self.warmup_steps = warmup_steps
-        self.on_straggler = on_straggler
-        self.ewma: float | None = None
-        self.count = 0
-        self.events: collections.deque[dict] = collections.deque(
-            maxlen=max_events
-        )
-
-    def record(self, step: int, dt: float) -> bool:
-        """Returns True if this step is flagged as a straggler event."""
-        self.count += 1
-        if self.ewma is None:
-            self.ewma = dt
-            return False
-        flagged = (
-            self.count > self.warmup_steps and dt > self.threshold * self.ewma
-        )
-        if flagged:
-            self.events.append({"step": step, "dt": dt, "ewma": self.ewma})
-            if self.on_straggler:
-                self.on_straggler(step, dt, self.ewma)
-            # don't poison the EWMA with the outlier
-        else:
-            self.ewma = (1 - self.alpha) * self.ewma + self.alpha * dt
-        return flagged
 
 
 class Heartbeat:
@@ -132,7 +84,7 @@ def supervise(
     """Run ``run_fn(start_step) -> final_step`` under restart-on-failure.
 
     ``run_fn`` must itself restore from the latest checkpoint when invoked
-    (the launch/train.py loop does).  Any exception triggers a restart from
+    (:func:`repro.runtime.resilient.resilient_evolve` does).  Any exception triggers a restart from
     the last committed checkpoint, up to ``max_restarts`` times — the
     single-process analogue of a cluster controller rescheduling dead hosts.
     """

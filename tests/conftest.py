@@ -3,21 +3,19 @@
 x64 is enabled for the numerics tests (the paper's solver is double
 precision); all code under test is dtype-explicit so this only widens the
 oracles.  Device count is left at 1 — multi-device tests spawn subprocesses
-with their own ``--xla_force_host_platform_device_count`` (the dry-run, and
-ONLY the dry-run, forces 512).
+with their own ``--xla_force_host_platform_device_count``.
 
-The subprocess-based distributed suites (domain decomposition, sharding
-dry-runs) take minutes; they are auto-marked ``slow`` so a quick iteration
-loop can deselect them with ``pytest -m "not slow"``."""
+The subprocess-based domain-decomposition suite takes minutes; it is
+auto-marked ``slow`` so a quick iteration loop can deselect it with
+``pytest -m "not slow"``."""
 
 import jax
 import pytest
 
 jax.config.update("jax_enable_x64", True)
 
-# modules / classes whose tests spawn multi-device subprocess dry-runs
+# modules whose tests spawn multi-device subprocesses
 _SLOW_MODULES = {"test_domain"}
-_SLOW_CLASSES = {"TestParamSpecInference"}
 
 
 def pytest_configure(config):
@@ -38,7 +36,5 @@ def pytest_configure(config):
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        if item.module.__name__ in _SLOW_MODULES or (
-            item.cls is not None and item.cls.__name__ in _SLOW_CLASSES
-        ):
+        if item.module.__name__ in _SLOW_MODULES:
             item.add_marker(pytest.mark.slow)

@@ -20,7 +20,7 @@ from repro.kernels.penta import (
     penta_factor,
     penta_solve_factored_mid,
 )
-from repro.launch.stream import stream_penta_solve_mid
+from repro.core.stream import stream_penta_solve_mid
 from repro.util import tolerance_for
 
 TOL = tolerance_for(jnp.float64)

@@ -33,7 +33,7 @@ from repro.kernels.penta import (
     penta_solve_factored_rows,
     tpu_sweep_problem,
 )
-from repro.launch.stream import stream_ch_rhs_xsweep, stream_penta_solve_rows
+from repro.core.stream import stream_ch_rhs_xsweep, stream_penta_solve_rows
 from repro.util import tolerance_for
 
 TOL = tolerance_for(jnp.float64)

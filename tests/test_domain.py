@@ -4,6 +4,7 @@ These spawn subprocesses with ``--xla_force_host_platform_device_count=8``
 so the main pytest process keeps its single real device."""
 
 import json
+import os
 import subprocess
 import sys
 
@@ -95,7 +96,16 @@ def multidevice_results():
         capture_output=True,
         text=True,
         timeout=1800,  # 8-device host compiles; generous for loaded CI boxes
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        # the parent's environment, held to the CPU: with libtpu installed
+        # an unset JAX_PLATFORMS lets the child take a TPU backend, which
+        # has no 8 devices for the (4, 2) mesh
+        env={
+            **os.environ,
+            "PYTHONPATH": os.pathsep.join(
+                p for p in ("src", os.environ.get("PYTHONPATH")) if p
+            ),
+            "JAX_PLATFORMS": "cpu",
+        },
         cwd=".",
     )
     assert proc.returncode == 0, proc.stderr[-3000:]

@@ -3,7 +3,7 @@
 import jax
 import jax.numpy as jnp
 
-from repro.launch.hlo_costs import analyze_hlo, parse_module, execution_counts
+from repro.analysis.cost import analyze_hlo, parse_module, execution_counts
 
 
 def compile_text(fn, *sds):

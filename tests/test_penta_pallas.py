@@ -20,7 +20,7 @@ from repro.kernels.penta import (
     penta_solve_factored,
 )
 from repro.kernels.ops import penta_solve
-from repro.launch.stream import stream_penta_solve
+from repro.core.stream import stream_penta_solve
 from repro.util import tolerance_for
 
 # shared helpers: base fp64 tolerance, scaled for the longer rounding

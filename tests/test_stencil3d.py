@@ -25,7 +25,7 @@ from repro.core.stencil import (
 from repro.kernels import ops
 from repro.kernels.ref import stencil3d_ref
 from repro.kernels.stencil3d import stencil3d_pallas
-from repro.launch.stream import stream_stencil3d_apply
+from repro.core.stream import stream_stencil3d_apply
 from repro.util import tolerance_for
 
 

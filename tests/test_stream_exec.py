@@ -11,7 +11,7 @@ from repro.core.stencil import stencil_create_1d_batch, stencil_create_2d
 from repro.kernels import ops
 from repro.kernels.ref import stencil1d_batch_ref, stencil2d_ref
 from repro.util import tolerance_for
-from repro.launch.stream import (
+from repro.core.stream import (
     _effective_streams,
     choose_chunk_rows,
     n_chunks_for,
@@ -231,7 +231,7 @@ class TestPlanRouting:
 
     def test_resolve_compute_mirrors_monolithic_dispatch(self):
         from repro.kernels import ops
-        from repro.launch.stream import resolve_compute
+        from repro.core.stream import resolve_compute
 
         assert resolve_compute("pallas") == "pallas"
         assert resolve_compute("jnp") == "jnp"

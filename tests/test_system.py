@@ -40,34 +40,6 @@ class TestCahnHilliardPhysics:
         assert frac_separated > 0.5, frac_separated
 
 
-class TestTrainLoop:
-    """examples/train_lm.py path: loss decreases on real (synthetic) data."""
-
-    def test_train_driver_smoke(self):
-        from repro.launch.train import train_loop
-
-        metrics = train_loop(
-            arch="smollm-135m",
-            reduced=True,
-            steps=8,
-            global_batch=4,
-            seq_len=16,
-            checkpoint_dir=None,
-            log_every=4,
-        )
-        assert len(metrics) == 8
-        assert all(np.isfinite(m["loss"]) for m in metrics)
-
-    def test_serve_driver_smoke(self):
-        from repro.launch.cells import greedy_generate as generate
-
-        out = generate(
-            arch="smollm-135m", reduced=True,
-            prompt_tokens=[5, 6, 7], max_new_tokens=4,
-        )
-        assert len(out) == 7  # prompt + 4
-
-
 class TestBenchmarkHarness:
     def test_benchmarks_importable_and_listed(self):
         import benchmarks.run as brun
