@@ -28,9 +28,9 @@ autotuner (:mod:`repro.tune`): candidates are measured once per
 **3D** (:class:`ADIOperator3D`, :func:`make_adi_operator_3d`): the same
 Create/Compute split on ``(nz, ny, nx)`` fields with *three* transpose-free
 sweeps — x as a row-layout solve of the ``(nz*ny, nx)`` reshape, z as a
-column-layout solve of the ``(nz, ny*nx)`` reshape, and y through the new
-plane-layout substitution (recurrence along the middle axis), so a full 3D
-splitting step performs zero transposes.  ``operator='diffusion'`` swaps
+column-layout solve down the leading axis of the field as it lies, and y
+through the plane-layout substitution (recurrence along the middle axis),
+so a full 3D splitting step performs zero transposes.  ``operator='diffusion'`` swaps
 the hyperdiffusion band for the backward-Euler heat operator
 ``I - alpha delta^2`` (tridiagonal riding the pentadiagonal machinery).
 """
@@ -263,6 +263,9 @@ def _sweep_candidates(batch: int, fft: bool = False):
     if fft:
         cands.append({"backend": "fft"})
     if _ops.on_tpu():
+        # no tile: the dispatch's own choice from the shape (the dense-
+        # step recurrence where the shape allows it)
+        cands.append({"backend": "pallas"})
         for t in tile_candidates(batch):
             cands.append({"backend": "pallas", "tile": t})
     return cands
@@ -415,7 +418,9 @@ class ADIOperator3D:
     - :meth:`solve_y` — *plane* layout
       (:func:`~repro.kernels.penta.penta_solve_factored_mid`): recurrence
       along the middle axis, batch on planes × lanes;
-    - :meth:`solve_z` — column layout on the ``(nz, ny*nx)`` reshape.
+    - :meth:`solve_z` — column layout down the leading axis, on the field
+      as it lies where its (ny, nx) planes tile into (8, 128) blocks,
+      else on the ``(nz, ny*nx)`` reshape.
 
     ``streams``/``max_tile_bytes`` route each sweep through the streamed
     executor: x chunks rows, y chunks z-planes, z chunks columns — the
@@ -516,34 +521,33 @@ class ADIOperator3D:
 
     @obs.stage("adi.z")
     def solve_z(self, rhs: jnp.ndarray) -> jnp.ndarray:
-        """Solve L_z u = rhs along the z (first) axis — column layout on
-        the (nz, ny*nx) reshape, transpose-free."""
+        """Solve L_z u = rhs along the z (first) axis — on the field as it
+        lies (the dense lead layout) where its (ny, nx) planes tile into
+        (8, 128) blocks, else as a column layout on the (nz, ny*nx)
+        reshape; the streamed path always takes the reshape."""
         backend, unroll, cfg = self._cfg(self.z_cfg)
         if backend == "fft":
             return _fft_sweep(self.sym_z, rhs, axis=-3)
-        nz, ny, nx = rhs.shape
-        flat = rhs.reshape(nz, ny * nx)
         if self._should_stream(rhs):
+            nz, ny, nx = rhs.shape
             out = _stream.stream_penta_solve(
                 self.fac_z,
-                flat,
+                rhs.reshape(nz, ny * nx),
                 cyclic=self.cyclic,
                 streams=self.streams,
                 max_tile_bytes=self.max_tile_bytes,
                 backend=backend,
                 unroll=unroll,
             )
-        else:
-            solve = (
-                cyclic_penta_solve_factored
-                if self.cyclic
-                else penta_solve_factored
-            )
-            out = solve(
-                self.fac_z, flat, backend=backend, tn=cfg.get("tn"),
-                unroll=unroll,
-            )
-        return out.reshape(rhs.shape)
+            return out.reshape(rhs.shape)
+        solve = (
+            cyclic_penta_solve_factored
+            if self.cyclic
+            else penta_solve_factored
+        )
+        return solve(
+            self.fac_z, rhs, backend=backend, tn=cfg.get("tn"), unroll=unroll
+        )
 
     def grid_problems(self, shape) -> list:
         """Why this operator cannot sweep an ``(nz, ny, nx)`` box — factor

@@ -23,21 +23,40 @@ Three substitution layouts are provided, so a full ADI step — 2D *or*
 factors in its native layout):
 
 - *column layout* (:func:`penta_solve_factored`): systems along axis 0
-  (length M), batch along axis 1 — the y-sweep of an ``(ny, nx)`` field
-  and (reshaped to ``(nz, ny*nx)``) the z-sweep of an ``(nz, ny, nx)``
-  one.
+  (length M), batch along the rest — the y-sweep of an ``(ny, nx)``
+  field and the z-sweep of an ``(nz, ny, nx)`` one, which runs on the
+  field as it lies (the *lead* layout below) or on its ``(nz, ny*nx)``
+  reshape.
 - *row layout* (:func:`penta_solve_factored_rows`): batch along axis 0,
   recurrence along axis 1 (TPU lanes) — the x-sweep, with no
   interleaving transpose of the field.  The Pallas variant transposes
-  each block's 128-lane chunks into a VMEM scratch and runs the column
+  each block's 128-lane chunks into a VMEM scratch and runs the
   recurrence there, on sublanes; the jnp variant walks the lanes with a
   ``fori_loop`` of dynamic column slices.  Reshaped to ``(nz*ny, nx)``
   it is also the 3D x-sweep.
 - *plane layout* (:func:`penta_solve_factored_mid`): batch along axes 0
   and 2 of a ``(P, M, N)`` stack, recurrence along the *middle* axis —
   the y-sweep of a 3D field, where neither reshape nor transpose can
-  bring the systems to an edge axis.  The carry is a full (P, N) plane;
-  the Pallas variant runs one z-plane × lane-tile per grid step.
+  bring the systems to an edge axis.  The carry is a full (P, N) plane.
+
+The Pallas kernels run one of two recurrences, both on sublanes; the
+dispatch picks from the shape alone (:func:`dense_sweep_tiles`):
+
+- *dense steps* (:func:`sweep_lines_refs`): every step loads whole
+  (8, 128) tiles, each holding step ``i`` of 8 x 128 independent lines,
+  so one step's latency is paid for 1024 lines a tile.  It needs a
+  second batch axis that fills the sublanes: a 3D z-sweep whose (ny, nx)
+  planes tile into (8, 128) blocks (step ``i`` is plane ``i`` of an
+  (nz, 8a, 128b) block), a plane sweep over a multiple of 8 planes at
+  128-lane tiles (step ``i`` reads row ``i`` of 8 planes, sublane-
+  strided, from the free (P*M, N) view), and a row sweep over a
+  multiple of 1024 rows whose (1024k, M) block fits VMEM (each 128-row
+  group transposed into its own M rows of the scratch).
+- *one-sublane steps* (:func:`sweep_refs`): every other shape — the 2D
+  column sweep (one plane), plane counts that are not a multiple of 8,
+  the 2D row sweep and the fused CH kernel where 1024-row blocks exceed
+  VMEM (M = 4096), and any explicit ``tn``/``tb`` tile.  Each step
+  works on one sublane row of one (8, 128) vreg.
 
 The rank-4 Woodbury correction is evaluated as four explicit outer
 products (broadcast FMAs) rather than ``dot``s: the (M, 4) x (4, N)
@@ -49,6 +68,7 @@ substitution.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -57,6 +77,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.util import block_spec, pick_tile
 
 
@@ -294,12 +315,15 @@ def sweep_refs(f_ref, o_ref) -> None:
     entry and the solution on exit; ``f_ref`` is the (5, M) SMEM factor
     table.
 
-    The one recurrence of every layout: column and plane sweeps run it on
+    The recurrence of the shapes with no batch axis that fills the
+    sublanes (:func:`dense_sweep_tiles` is 0; else
+    :func:`sweep_lines_refs` runs): column and plane sweeps run it on
     their (1, M, tn) blocks, the row sweep and the fused CH kernel on an
     (M, tb) VMEM scratch that :func:`sweep_rows_refs` transposes their
     rows into.  Mosaic refuses unaligned single-row accesses, so the loop
     loads and stores whole aligned blocks of :func:`_chunk` steps and runs
-    the steps inside a block unrolled, on static slices.
+    the steps inside a block unrolled, on static slices: each step works
+    on one sublane of a vreg and selects it back into the block.
     """
     nd = len(o_ref.shape)
     axis = nd - 2
@@ -390,6 +414,94 @@ def sweep_rows_refs(f_ref, r_ref, o_ref, t_ref) -> None:
     move(t_ref, rows, o_ref, lanes)
 
 
+def sweep_lines_refs(f_ref, r_ref, o_ref, at) -> None:
+    """Dense-step banded substitution: each step of the recurrence works
+    on whole (8, 128) tiles of independent lines.  ``at(i)`` lists the
+    indexers of the tiles that hold recurrence index ``i`` in ``r_ref``
+    (the right-hand side) and ``o_ref`` (the solution, which may be
+    ``r_ref``); ``f_ref`` is the (5, M) SMEM factor table.
+
+    A step loads its tiles, applies the forward substitution (the
+    backward one, walking back) in the order of :func:`_substitute_jnp`,
+    and stores them whole: no select, no sublane slice, and the step's
+    tiles are independent work beside the carry's dependent chain.
+    """
+    M = f_ref.shape[1]
+    # a zero laid out like a loaded tile (see sweep_refs)
+    zero = tuple(r_ref[idx] * 0 for idx in at(jnp.int32(0)))
+
+    def fwd(i, carry):
+        z1, z2 = carry
+        e, low, imu = f_ref[0, i], f_ref[1, i], f_ref[2, i]
+        z = tuple(
+            (r_ref[idx] - e * b - low * a) * imu
+            for idx, a, b in zip(at(i), z1, z2)
+        )
+        for idx, v in zip(at(i), z):
+            o_ref[idx] = v
+        return z, z1
+
+    def bwd(t, carry):
+        x1, x2 = carry
+        i = np.int32(M - 1) - t
+        al, be = f_ref[3, i], f_ref[4, i]
+        x = tuple(
+            o_ref[idx] - al * a - be * b for idx, a, b in zip(at(i), x1, x2)
+        )
+        for idx, v in zip(at(i), x):
+            o_ref[idx] = v
+        return x, x1
+
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(M), fwd, (zero, zero))
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(M), bwd, (zero, zero))
+
+
+def _strided_lines(M: int, k: int):
+    """``at`` of :func:`sweep_lines_refs` on an (8k*M, 128) block of 8k
+    line groups of M rows each: tile ``g`` of step ``i`` is row ``i`` of
+    groups 8g..8g+7, one sublane-strided access."""
+
+    def at(i):
+        return [
+            (pl.ds(i + np.int32(8 * g * M), 8, stride=M), slice(None))
+            for g in range(k)
+        ]
+
+    return at
+
+
+def sweep_row_lines_refs(f_ref, r_ref, o_ref, t_ref) -> None:
+    """Dense row layout: the (128*8k, M) block ``r_ref`` holds 8k groups
+    of 128 systems (rows).  Each group's lane chunks (of 128, or of all
+    of ``M``) are transposed into rows g*M.. of the (8k*M, 128) VMEM
+    scratch ``t_ref``, :func:`sweep_lines_refs` steps eight groups a
+    tile, and the chunks are transposed back into ``o_ref``."""
+    B, M = r_ref.shape
+    c = 128 if M % 128 == 0 else M
+    per = M // c
+
+    def move(into_scratch):
+        def body(j, carry):
+            g, q = j // np.int32(per), j % np.int32(per)
+            off = q * np.int32(c)
+            lines = (
+                pl.ds(pl.multiple_of(g * np.int32(128), 128), 128),
+                pl.ds(pl.multiple_of(off, c), c),
+            )
+            rows = pl.ds(pl.multiple_of(g * np.int32(M) + off, 8), c), slice(None)
+            if into_scratch:
+                t_ref[rows] = r_ref[lines].T
+            else:
+                o_ref[lines] = t_ref[rows].T
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(B // 128 * per), body, None)
+
+    move(True)
+    sweep_lines_refs(f_ref, t_ref, t_ref, _strided_lines(M, B // 1024))
+    move(False)
+
+
 def _pad(n: int, m: int) -> int:
     return -(-n // m) * m
 
@@ -420,6 +532,52 @@ def tpu_sweep_problem(M, batch, tile, dtype, *, lanes: bool) -> str | None:
     return None
 
 
+# (8, 128) tiles of independent lines one dense recurrence step works on:
+# independent vregs beside the carry's chain of dependent operations
+STEP_TILES = 4
+
+
+def _lead_block(k: int, B1: int, B2: int) -> tuple[int, int] | None:
+    """The (8a, 128b) plane block, a * b = ``k``, that tiles (B1, B2),
+    widest first; ``None`` where none does."""
+    for b in range(k, 0, -1):
+        if k % b == 0 and B1 % (8 * (k // b)) == 0 and B2 % (128 * b) == 0:
+            return 8 * (k // b), 128 * b
+    return None
+
+
+def dense_sweep_tiles(layout: str, M: int, batch, dtype) -> int:
+    """How many (8, 128) tiles of independent lines one dense recurrence
+    step (:func:`sweep_lines_refs`) can work on at this shape, at most
+    :data:`STEP_TILES`; 0 where no batch axis yields whole groups of
+    8 x 128 lines within :data:`VMEM_LIMIT_BYTES`, and the one-sublane
+    :func:`sweep_refs` runs.  The layouts and their blocks (k tiles):
+
+    - ``'lead'``, an (M, B1, B2) rhs, ``batch = (B1, B2)``: (M, 8a, 128b)
+      blocks, a * b = k; step ``i`` is the block's plane ``i``.
+    - ``'mid'``, a (P, M, N) rhs, ``batch = (P, N)``: (8k M, 128) blocks of
+      the free (P M, N) view; a tile is row ``i`` of 8 planes.
+    - ``'rows'``, a (B, M) rhs, ``batch = (B,)``: (1024 k, M) blocks, whose
+      groups of 128 rows are transposed into an (8k M, 128) scratch."""
+    if jnp.dtype(dtype).itemsize != 4 or M > SMEM_MAX_M:
+        return 0
+    if layout != "lead" and M % 8:
+        return 0
+    for k in range(STEP_TILES, 0, -1):
+        if layout == "lead":
+            fits = _lead_block(k, *batch) is not None
+            words = 4 * M * 1024 * k  # in + out, double-buffered
+        elif layout == "mid":
+            fits = batch[0] % (8 * k) == 0 and batch[1] % 128 == 0
+            words = 4 * 8 * k * M * 128
+        else:
+            fits = batch[0] % (1024 * k) == 0
+            words = 4 * 1024 * k * _pad(M, 128) + 8 * k * M * 128
+        if fits and 4 * words <= VMEM_LIMIT_BYTES:
+            return k
+    return 0
+
+
 def _smem_table_spec(M: int):
     """The whole (5, M) factor table in SMEM at every grid step."""
     return block_spec((5, M), lambda *_: (0, 0), memory_space=pltpu.SMEM)
@@ -430,16 +588,20 @@ def _solve_kernel(f_ref, r_ref, o_ref):
     sweep_refs(f_ref, o_ref)
 
 
+def _lines_kernel(f_ref, r_ref, o_ref, *, at):
+    sweep_lines_refs(f_ref, r_ref, o_ref, at)
+
+
 def _pallas_sweep(
     kernel, fac, rhs, *, block, index_map, grid, interpret, scratch_shapes=()
 ):
-    """One sweep ``pallas_call``: the recurrence runs along axis 1 of every
-    ``block`` (the middle axis of a plane block, the lanes of a row block)."""
+    """One sweep ``pallas_call`` of ``kernel`` over ``block``s of ``rhs``,
+    the factor table in SMEM."""
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            _smem_table_spec(rhs.shape[1]),
+            _smem_table_spec(fac.sub.shape[0]),
             block_spec(block, index_map),
         ],
         out_specs=block_spec(block, index_map),
@@ -450,14 +612,26 @@ def _pallas_sweep(
     )(_fac_table(fac), rhs)
 
 
-@functools.partial(jax.jit, static_argnames=("tn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tn", "interpret", "tiles"))
 def _substitute_mid_pallas(
-    fac: PentaFactors, rhs: jnp.ndarray, *, tn: int, interpret: bool
+    fac: PentaFactors, rhs: jnp.ndarray, *, tn: int, interpret: bool,
+    tiles: int = 0,
 ) -> jnp.ndarray:
-    """Plane layout on (P, M, N): one z-plane × lane tile per grid step."""
+    """Plane layout on (P, M, N): one z-plane × lane tile per grid step,
+    or, with ``tiles`` = k, 8k planes × 128 lanes of the free (P M, N)
+    view per grid step, each recurrence step k sublane-strided tiles."""
     P, M, N = rhs.shape
     if N % tn:
         raise ValueError(f"lane tile {tn} must divide N={N}")
+    if tiles:
+        rows = 8 * tiles * M
+        out = _pallas_sweep(
+            functools.partial(_lines_kernel, at=_strided_lines(M, tiles)),
+            fac, rhs.reshape(P * M, N), block=(rows, 128),
+            index_map=lambda p, j: (p, j), grid=(P * M // rows, N // 128),
+            interpret=interpret,
+        )
+        return out.reshape(P, M, N)
     return _pallas_sweep(
         _solve_kernel, fac, rhs, block=(1, M, tn),
         index_map=lambda p, i: (p, 0, i),
@@ -475,19 +649,45 @@ def _substitute_pallas(
     return _substitute_mid_pallas(fac, rhs[None], tn=tn, interpret=interpret)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("tb", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tb", "interpret", "tiles"))
 def _substitute_rows_pallas(
-    fac: PentaFactors, rhs: jnp.ndarray, *, tb: int, interpret: bool
+    fac: PentaFactors, rhs: jnp.ndarray, *, tb: int, interpret: bool,
+    tiles: int = 0,
 ) -> jnp.ndarray:
     """Row layout on (B, M): each (tb, M) block is transposed in VMEM
-    (:func:`sweep_rows_refs`) and the recurrence walks its sublanes."""
+    (:func:`sweep_rows_refs`) and the recurrence walks its sublanes; with
+    ``tiles`` = k, (1024k, M) blocks go through
+    :func:`sweep_row_lines_refs`."""
     B, M = rhs.shape
     if B % tb:
         raise ValueError(f"batch tile {tb} must divide B={B}")
+    if tiles:
+        rows = 1024 * tiles
+        return _pallas_sweep(
+            sweep_row_lines_refs, fac, rhs, block=(rows, M),
+            index_map=lambda i: (i, 0), grid=(B // rows,),
+            interpret=interpret,
+            scratch_shapes=[pltpu.VMEM((8 * tiles * M, 128), rhs.dtype)],
+        )
     return _pallas_sweep(
         sweep_rows_refs, fac, rhs, block=(tb, M), index_map=lambda i: (i, 0),
         grid=(B // tb,), interpret=interpret,
         scratch_shapes=[pltpu.VMEM((M, tb), rhs.dtype)],
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _substitute_lead_pallas(
+    fac: PentaFactors, rhs: jnp.ndarray, *, block: tuple, interpret: bool
+) -> jnp.ndarray:
+    """Lead layout on (M, B1, B2), systems down the leading axis: an
+    (M, *block) block per grid step, whose plane ``i`` is recurrence step
+    ``i`` of 8a x 128b lines — dense in place, with no copy of the field."""
+    M, B1, B2 = rhs.shape
+    return _pallas_sweep(
+        functools.partial(_lines_kernel, at=lambda i: [(i,)]), fac, rhs,
+        block=(M, *block), index_map=lambda j, l: (0, j, l),
+        grid=(B1 // block[0], B2 // block[1]), interpret=interpret,
     )
 
 
@@ -530,26 +730,45 @@ def penta_solve_factored(
     interpret: bool | None = None,
     unroll: int = 1,
 ) -> jnp.ndarray:
-    """Solve ``A x = rhs`` given Create-time factors.  rhs: (M,) or (M, N)."""
+    """Solve ``A x = rhs`` given Create-time factors.  rhs: (M,), (M, N)
+    or (M, B1, B2), the systems down axis 0.
+
+    A 3D rhs whose (B1, B2) planes tile into (8, 128) blocks runs the
+    dense lead-layout kernel on the field as it lies; any other batch is
+    the column layout (a 3D one on its (M, B1 B2) reshape).  ``tn`` pins
+    the column layout's lane tile."""
     from repro.kernels import ops  # cycle-free: ops imports names only
 
     squeeze = rhs.ndim == 1
     if squeeze:
         rhs = rhs[:, None]
-    M, N = rhs.shape
+    shape = rhs.shape
+    M, N = shape[0], math.prod(shape[1:])
+    tiles = 0
+    if rhs.ndim == 3 and tn is None:
+        tiles = dense_sweep_tiles("lead", M, shape[1:], rhs.dtype)
     tn = tn if tn is not None else pick_tile(N)
-    backend = ops.checked_backend(
-        "penta sweep", tpu_sweep_problem(M, N, tn, rhs.dtype, lanes=False),
-        backend, interpret,
+    problem = None if tiles else tpu_sweep_problem(
+        M, N, tn, rhs.dtype, lanes=False
     )
+    backend = ops.checked_backend("penta sweep", problem, backend, interpret)
+    if backend == "pallas" and tiles:
+        obs.add("penta.dense_sweeps", 1)
+        return _substitute_lead_pallas(
+            fac, rhs, block=_lead_block(tiles, *shape[1:]),
+            interpret=ops._should_interpret(interpret),
+        )
+    flat = rhs.reshape(M, N) if rhs.ndim == 3 else rhs
     if backend == "pallas":
         out = _substitute_pallas(
-            fac, rhs, tn=tn, interpret=ops._should_interpret(interpret)
+            fac, flat, tn=tn, interpret=ops._should_interpret(interpret)
         )
     elif backend == "jnp":
-        out = _substitute_jnp_jit(fac, rhs, unroll=unroll)
+        out = _substitute_jnp_jit(fac, flat, unroll=unroll)
     else:
         raise ValueError(f"unknown backend {backend!r}")
+    if rhs.ndim == 3:
+        out = out.reshape(shape)
     return out[:, 0] if squeeze else out
 
 
@@ -565,7 +784,9 @@ def penta_solve_factored_rows(
     """Row-layout solve: ``rhs`` is (B, M) (or (M,)), each *row* one system.
 
     The x-sweep, with no transpose of the field in HBM — same factors as
-    :func:`penta_solve_factored`, recurrence along axis 1.
+    :func:`penta_solve_factored`, recurrence along axis 1.  ``tb`` pins
+    the one-sublane sweep's row tile; left ``None``, a batch of whole
+    1024-row blocks runs the dense-step sweep.
     """
     from repro.kernels import ops
 
@@ -573,14 +794,18 @@ def penta_solve_factored_rows(
     if squeeze:
         rhs = rhs[None, :]
     B, M = rhs.shape
+    tiles = dense_sweep_tiles("rows", M, (B,), rhs.dtype) if tb is None else 0
     tb = tb if tb is not None else pick_tile(B)
-    backend = ops.checked_backend(
-        "penta sweep", tpu_sweep_problem(M, B, tb, rhs.dtype, lanes=True),
-        backend, interpret,
+    problem = None if tiles else tpu_sweep_problem(
+        M, B, tb, rhs.dtype, lanes=True
     )
+    backend = ops.checked_backend("penta sweep", problem, backend, interpret)
     if backend == "pallas":
+        if tiles:
+            obs.add("penta.dense_sweeps", 1)
         out = _substitute_rows_pallas(
-            fac, rhs, tb=tb, interpret=ops._should_interpret(interpret)
+            fac, rhs, tb=tb, tiles=tiles,
+            interpret=ops._should_interpret(interpret),
         )
     elif backend == "jnp":
         out = _substitute_rows_jnp_jit(fac, rhs, unroll=unroll)
@@ -603,18 +828,24 @@ def penta_solve_factored_mid(
 
     The transpose-free y-sweep of a 3D ADI step: same Create-time factors
     as :func:`penta_solve_factored`, batch on the outer planes × lanes.
+    ``tn`` pins the one-sublane sweep's lane tile; left ``None``, a
+    multiple of 8 planes at 128-lane tiles runs the dense-step sweep.
     """
     from repro.kernels import ops
 
     P, M, N = rhs.shape
+    tiles = dense_sweep_tiles("mid", M, (P, N), rhs.dtype) if tn is None else 0
     tn = tn if tn is not None else pick_tile(N)
-    backend = ops.checked_backend(
-        "penta sweep", tpu_sweep_problem(M, N, tn, rhs.dtype, lanes=False),
-        backend, interpret,
+    problem = None if tiles else tpu_sweep_problem(
+        M, N, tn, rhs.dtype, lanes=False
     )
+    backend = ops.checked_backend("penta sweep", problem, backend, interpret)
     if backend == "pallas":
+        if tiles:
+            obs.add("penta.dense_sweeps", 1)
         return _substitute_mid_pallas(
-            fac, rhs, tn=tn, interpret=ops._should_interpret(interpret)
+            fac, rhs, tn=tn, tiles=tiles,
+            interpret=ops._should_interpret(interpret),
         )
     if backend == "jnp":
         return _substitute_mid_jnp_jit(fac, rhs, unroll=unroll)
@@ -677,7 +908,7 @@ def cyclic_penta_solve_factored(
         unroll=unroll,
     )
     M = y.shape[0]
-    w = fac.w
+    w = fac.w if y.ndim == 2 else fac.w[:, :, None]  # (M, B1, B2): planes
     x = y - (
         w[:, 0:1] * y[M - 2][None, :]
         + w[:, 1:2] * y[M - 1][None, :]

@@ -26,8 +26,13 @@ holds the library's names beside the device ops, on one clock:
   with a static tap set (weights known when the program was traced, some
   of them zero), and ``stencil3d.taps_skipped``, the zero taps those
   applies dropped (100 for the 5x5x5 biharmonic, 20 for the 7-point
-  Laplacian).  Take the difference of two readings around the part of a
-  run you want to count.
+  Laplacian).  And one the penta dispatch adds as a sweep is traced:
+  ``penta.dense_sweeps``, the sweeps sent to the dense-step recurrence
+  (:func:`repro.kernels.penta.sweep_lines_refs`), which fills every
+  sublane with independent lines: 3 a 3D ADI step at the benchmark's
+  sizes, 0 a 2D one (no batch axis there yields 8 x 128 lines in VMEM).
+  Take the difference of two readings around the part of a run you want
+  to count.
 """
 
 from __future__ import annotations
@@ -53,6 +58,7 @@ _lock = threading.Lock()
 _counts = {
     "programs": 0, "compile_s": 0.0, "cache_hits": 0, "cache_misses": 0,
     "stencil3d.sparse_applies": 0, "stencil3d.taps_skipped": 0,
+    "penta.dense_sweeps": 0,
 }
 
 
@@ -100,8 +106,9 @@ def counters() -> dict:
     ``compile_s`` (their seconds), ``cache_hits``/``cache_misses`` (the
     persistent compile cache), ``stencil3d.sparse_applies``/
     ``stencil3d.taps_skipped`` (3D stencil applies traced with a tap set,
-    and the zero taps they dropped), and the Create-time tuner's counts
-    as ``tune.*`` (read from :data:`repro.tune.stats`)."""
+    and the zero taps they dropped), ``penta.dense_sweeps`` (penta sweeps
+    traced on the dense-step recurrence), and the Create-time tuner's
+    counts as ``tune.*`` (read from :data:`repro.tune.stats`)."""
     from repro.tune import stats
 
     with _lock:

@@ -171,3 +171,32 @@ def test_host_spans_reach_a_profile(tmp_path):
     assert sorted(set(names)) == ["custen.create", "custen.evolve.bootstrap",
                                   "custen.evolve.chunk", "custen.evolve.metrics"]
     assert names.count("custen.evolve.chunk") == 2
+
+
+def _dense_sweeps_traced(fn, *shapes):
+    before = obs.counters()["penta.dense_sweeps"]
+    jax.eval_shape(fn, *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
+    return obs.counters()["penta.dense_sweeps"] - before
+
+
+@pytest.mark.parametrize(
+    "program, dense",
+    [
+        # 3D CH: the x, y and z sweeps of a step fill every sublane
+        (lambda: (CahnHilliardADI(CHConfig(
+            nx=128, ny=128, nz=8, dt=1e-3, dtype="float32", rhs_mode="stencil",
+            backend="pallas")).make_evolve(4), [(8, 128, 128)] * 2), 3),
+        # LOD heat: one repro.compute, its three sweeps
+        (lambda: ((lambda op: lambda c: repro.compute(op, c))(repro.create(
+            "diffusion", (8, 128, 128), mode="adi", alpha=1.0,
+            dtype=jnp.float32, backend="pallas")), [(8, 128, 128)]), 3),
+        # 2D CH: the fused RHS + x-sweep and the one-plane column sweep
+        (lambda: (CahnHilliardADI(CHConfig(
+            nx=64, ny=64, dt=1e-3, dtype="float32", backend="pallas"))
+            .make_evolve(4), [(64, 64)] * 2), 0),
+    ],
+    ids=["ch3d_evolve", "heat_lod_step", "ch2d_evolve"],
+)
+def test_dense_sweeps_counts_the_sweeps_of_a_traced_step(program, dense):
+    fn, shapes = program()
+    assert _dense_sweeps_traced(fn, *shapes) == dense

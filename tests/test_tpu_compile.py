@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -176,6 +178,30 @@ def test_penta_plane(one_chip, tpu_dispatch):
     )
 
 
+_FIELD_MOVE = re.compile(
+    r"=\s*f32\[([\d,]+)\]\S*\s+(copy|transpose|reshape)\("
+)
+
+
+@pytest.mark.parametrize("axis", ["x", "y", "z"])
+@pytest.mark.parametrize("n", [512, 256])
+def test_adi3d_sweeps_dense(one_chip, tpu_dispatch, n, axis):
+    # each sweep of a 3D ADI step takes the dense-step recurrence, and the
+    # z sweep runs on the field as it lies: no copy, transpose or reshape
+    # of the whole field anywhere in its program
+    op = repro.create("hyperdiffusion", (n, n, n), mode="adi", alpha=0.05,
+                      dtype=F32, backend="auto")
+    c = jax.ShapeDtypeStruct((n, n, n), F32, sharding=one_chip)
+    before = obs.counters()["penta.dense_sweeps"]
+    text = jax.jit(getattr(op, "solve_" + axis)).lower(c).compile().as_text()
+    assert obs.counters()["penta.dense_sweeps"] == before + 1
+    assert "tpu_custom_call" in text
+    if axis == "z":
+        moves = [m.group(0) for m in _FIELD_MOVE.finditer(text)
+                 if np.prod([int(d) for d in m.group(1).split(",")]) == n**3]
+        assert moves == [], moves
+
+
 def test_ch_rhs_xsweep(one_chip, tpu_dispatch):
     _compile(
         lambda a, b, f: ops.ch_rhs_xsweep(a, b, f, **CH_KW),
@@ -203,8 +229,6 @@ def test_float64_stays_off_mosaic(tpu_dispatch):
 def test_ch_evolve_kernels_carry_their_sweep_stage(one_chip, tpu_dispatch):
     # every Pallas kernel of the CH hot loop is charged to its ADI sweep:
     # the fused RHS + x-sweep to custen.adi.x, the column penta to adi.y
-    import re
-
     from repro.core.cahn_hilliard import CahnHilliardADI, CHConfig
 
     n = 256
@@ -223,8 +247,6 @@ def test_ch_evolve_kernels_carry_their_sweep_stage(one_chip, tpu_dispatch):
 
 def _outermost_stages(text):
     """The outermost ``custen.`` stage of each Pallas kernel's ``op_name``."""
-    import re
-
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels, "dispatch did not pick the Pallas kernels"
